@@ -28,12 +28,12 @@ that is exactly the enqueue-time-sequence idiom. The sanctioned
 fallback sites (the end-of-run barrier every process reaches the same
 number of times) carry ``disable=barrier-discipline -- why`` in place.
 
-**XLA collectives on coordination paths.** jax 0.4.37 on CPU has no
-multi-process XLA: ``sync_global_devices`` / ``process_allgather`` /
-``lax.psum``-family calls on a coordination-only path either crash the
-backend or queue device work behind the step stream from a worker
-thread. Coordination paths use the coordination-service KV store,
-full stop. (SPMD collectives on the main compute path — ``test()``'s
+**XLA collectives on coordination paths.** ``sync_global_devices`` /
+``process_allgather`` / ``lax.psum``-family calls on a
+coordination-only path queue device work behind the step stream from
+a worker thread, and hang the moment one process takes the path and
+another does not. Coordination paths use the coordination-service KV
+store, full stop. (SPMD collectives on the main compute path — ``test()``'s
 gather — are out of scope by construction: they are not reachable
 from the coordination seeds.)
 
@@ -325,8 +325,7 @@ class BarrierDisciplineRule(Rule):
             yield Finding(
                 self.name, sf.relpath, node.lineno,
                 f"XLA collective `{hit}` on coordination path "
-                f"`{key[1]}` — jax 0.4.37 CPU has no multi-process "
-                "XLA, and a collective from a coordination thread "
+                f"`{key[1]}` — a collective from a coordination thread "
                 "queues device work behind the step stream; use the "
                 "coordination-service KV store "
                 "(docs/DURABILITY.md)",

@@ -2,13 +2,13 @@
 installed jax.
 
 The defect class this rule exists for shipped in the seed:
-``hydragnn_tpu/parallel/graphshard.py`` called ``jax.shard_map``, which
-does not exist in jax 0.4.37 (it lives in
-``jax.experimental.shard_map``) — breaking every graph-sharding test
-and the giant-graph examples until the first run hit the
-AttributeError. jax moves APIs between minor releases constantly
-(``jax.ops``, ``jax.tree_util``, experimental promotions), so chains
-are resolved against the interpreter's actual jax at lint time, not a
+``hydragnn_tpu/parallel/graphshard.py`` called a top-level ``jax.*``
+name the then-installed jax kept under ``jax.experimental`` — breaking
+every graph-sharding test and the giant-graph examples until the first
+run hit the AttributeError. jax moves APIs between minor releases
+constantly (``jax.ops``, ``jax.tree_util``, experimental promotions
+and removals such as ``jax.experimental.enable_x64``), so chains are
+resolved against the interpreter's actual jax at lint time, not a
 vendored stub.
 
 Mechanics: for each module, import aliases rooted at jax are tracked
@@ -16,16 +16,14 @@ Mechanics: for each module, import aliases rooted at jax are tracked
 jax.sharding import PartitionSpec as P``, ...); every Load-context
 attribute chain whose base resolves into jax is then checked attribute
 by attribute, importing not-yet-imported submodules along the way
-(``jax.experimental.shard_map`` is a real module even though
+(``jax.experimental.checkify`` is a real module even though
 ``jax.experimental`` does not re-export it). From-import statements of
 jax modules are checked the same way. ``getattr(jax, "name", ...)``
-probes are invisible to this rule by construction — that is the
-sanctioned version-tolerant accessor pattern (see
-``hydragnn_tpu/parallel/graphshard.py``).
+probes are invisible to this rule by construction.
 
 When a top-level attribute is missing, the rule probes
-``jax.experimental.<name>`` and suggests the relocation if it exists —
-which is precisely the shard_map case.
+``jax.experimental.<name>`` and suggests the relocation if it exists
+(``jax.checkify`` -> ``jax.experimental.checkify.checkify``).
 """
 
 from __future__ import annotations
@@ -102,7 +100,7 @@ _MISSING = object()
 
 def _relocation_hint(prefix: List[str], attr: str) -> Optional[str]:
     """Probe the common jax relocation target: an experimental submodule
-    exporting an attribute of its own name (shard_map, pallas, ...)."""
+    exporting an attribute of its own name (checkify, jet, ...)."""
     if prefix != ["jax"]:
         return None
     mod = _import_maybe(f"jax.experimental.{attr}")

@@ -1,7 +1,9 @@
 """ctypes bindings for the native host components.
 
-Builds ``libhgtpu_native.so`` from the C++ sources on first use (g++ -O3,
-cached next to the sources keyed by source mtime) and exposes:
+Builds ``libhgtpu_native.<hash>.so`` from the C++ sources on first use
+(g++ -O3, cached next to the sources and keyed by a hash of their
+contents, so a binary built from other sources is never loaded) and
+exposes:
 
 - ``radius_graph_native`` / ``radius_graph_pbc_native`` — cell-list
   neighbor builders (vesin replacement, see celllist.cpp);
@@ -16,6 +18,8 @@ hydragnn_tpu/ops/neighbors.py when it is False.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -41,9 +45,14 @@ def _build() -> Optional[ctypes.CDLL]:
         os.path.join(_HERE, "celllist.cpp"),
         os.path.join(_HERE, "samplestore.cpp"),
     ]
-    out = os.path.join(_HERE, "libhgtpu_native.so")
-    stamp = max(os.path.getmtime(s) for s in sources)
-    if not os.path.exists(out) or os.path.getmtime(out) < stamp:
+    digest = hashlib.sha256()
+    for src in sources:
+        with open(src, "rb") as f:
+            digest.update(f.read())
+    out = os.path.join(
+        _HERE, f"libhgtpu_native.{digest.hexdigest()[:16]}.so"
+    )
+    if not os.path.exists(out):
         # Compile to a per-process temp path and atomically rename so
         # concurrent processes never load a half-written library. No
         # -march=native: the cached .so may travel to a different CPU
@@ -64,13 +73,19 @@ def _build() -> Optional[ctypes.CDLL]:
                 cmd, check=True, capture_output=True, timeout=120
             )
             os.replace(tmp, out)
-        except Exception:
+        except (OSError, subprocess.SubprocessError):
             try:
                 os.unlink(tmp)
             except OSError:
                 pass
-            if not os.path.exists(out):
-                return None
+            return None
+        # binaries of other sources are dead weight next to this one
+        for stale in glob.glob(os.path.join(_HERE, "libhgtpu_native*.so")):
+            if stale != out:
+                try:
+                    os.unlink(stale)
+                except OSError:
+                    pass
     try:
         lib = ctypes.CDLL(out)
     except OSError:

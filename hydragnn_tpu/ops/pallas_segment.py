@@ -291,7 +291,11 @@ def plan_sorted_blocks(
 
     ``edge_valid`` (optional [E] bool) masks padding edges out of the
     plan itself, replacing the pre-kernel ``where(edge_mask, ...)``
-    data copy the in-kernel gather exists to avoid.
+    data copy the in-kernel gather exists to avoid. It must leave each
+    block's valid rows ONE contiguous run (collate's mask does: padding
+    edges sort after every real one) — the kernels rebuild the mask
+    from the run's two end points (``_block_runs``); a mask with holes
+    raises here.
 
     Returns (perm, seg_padded, valid, window_id):
       perm      [B*be] int32 — edge row per slot (tile arange, clamped
@@ -320,6 +324,13 @@ def plan_sorted_blocks(
             rows_c = np.minimum(rows, max(e - 1, 0))
             if ev is not None:
                 valid &= ev[rows_c]
+                hit = np.flatnonzero(valid)
+                if len(hit) and hit[-1] - hit[0] + 1 != len(hit):
+                    raise ValueError(
+                        "edge_valid leaves holes inside a block's run "
+                        f"(window {w}, tile {t}): the planned kernels "
+                        "need each block's valid rows contiguous"
+                    )
             segs = np.full(be, w * bn, np.int64)
             segs[valid] = seg[rows_c[valid]]
             perm_l.append(rows_c)
@@ -334,11 +345,41 @@ def plan_sorted_blocks(
     )
 
 
+def _interpret() -> bool:
+    """Off-TPU there is no Mosaic backend: interpret mode keeps the
+    kernels differentially testable on the CPU mesh. On a TPU the
+    kernels always go through Mosaic, and a refusal raises — nothing
+    falls back to the XLA path on a compile error."""
+    return jax.default_backend() != "tpu"
+
+
+def _block_runs(valid: jax.Array, n_blocks: int, be: int):
+    """Per-block ``[lo, hi)`` of the valid slots. A block's valid rows
+    are ONE contiguous run of its tile (``plan_sorted_blocks`` enforces
+    it), so two scalars per block carry the whole mask — they ride
+    scalar prefetch and the kernels rebuild the mask from iotas in
+    whichever orientation (lane- or sublane-major) each use needs,
+    instead of relayouting a 1-D mask vector inside the kernel."""
+    v = valid.reshape(n_blocks, be)
+    lo = jnp.argmax(v, axis=1).astype(jnp.int32)
+    hi = lo + jnp.sum(v, axis=1, dtype=jnp.int32)
+    return lo, hi
+
+
+def _block_masks(lo, hi, be: int):
+    """(lane-major [1, be], sublane-major [be, 1]) masks of the valid
+    run ``[lo, hi)`` of one block, both built 2-D from iotas."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, be), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (be, 1), 0)
+    return (lane >= lo) & (lane < hi), (row >= lo) & (row < hi)
+
+
 def _edge_pipeline_kernel(
     tile_ref,  # [B] scalar prefetch (drives the input index_maps)
     window_ref,  # [B] scalar prefetch (drives the output index_map)
+    lo_ref,  # [B] scalar prefetch — first valid slot of the block
+    hi_ref,  # [B] scalar prefetch — one past the last valid slot
     seg_ref,
-    valid_ref,
     a_ref,
     *rest,
     bn,
@@ -354,10 +395,11 @@ def _edge_pipeline_kernel(
 
     i = pl.program_id(0)
     node_base = window_ref[i] * bn
-    local = seg_ref[0, :] - node_base  # [be]
+    lane_valid, row_valid = _block_masks(lo_ref[i], hi_ref[i], be)
+    # invalid slots match no window row
+    local = jnp.where(lane_valid, seg_ref[0:1, :] - node_base, -1)  # [1, be]
     rows = jax.lax.broadcasted_iota(jnp.int32, (bn, be), 0)
-    vmask = valid_ref[0, :] != 0
-    onehot = (local[None, :] == rows) & vmask[None, :]
+    onehot = local == rows
     # f32 data must not round through the MXU's bf16 multiplies; bf16
     # data multiplies natively (exact into the f32 MXU accumulator) —
     # including the h operand of the reduce matmul, which matches the
@@ -375,7 +417,7 @@ def _edge_pipeline_kernel(
     # SELECT (not multiply) against valid: rows past the array end of a
     # boundary tile are Pallas padding with unspecified values — a
     # select kills them even if they read back NaN.
-    block = jnp.where(vmask[:, None], block, jnp.zeros_like(block))
+    block = jnp.where(row_valid, block, 0.0)
     if has_w:
         # the dense layer's matmul in the same pass — this is what adds
         # real MXU flops per HBM byte (arithmetic intensity)
@@ -395,6 +437,15 @@ def _edge_pipeline_kernel(
     @pl.when(jnp.logical_not(is_first))
     def _():
         out_ref[:] = out_ref[:] + acc.astype(out_ref.dtype)
+
+
+def _seg_tiles(seg_padded: jax.Array, n_blocks: int, be: int) -> jax.Array:
+    """1-D int operands trip Mosaic's layout rules; ship per-block ids
+    as (8, be) tiles (sublane dim must be a multiple of 8) — each
+    block's ids replicated across the 8 sublanes."""
+    return jnp.repeat(
+        seg_padded.reshape(n_blocks, 1, be), 8, axis=1
+    ).reshape(n_blocks * 8, be)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "bn", "be"))
@@ -425,39 +476,40 @@ def _pallas_edge_pipeline(
     f_in = a.shape[1]
     f_out = w.shape[1] if w is not None else f_in
     n_pad = ((num_segments + bn - 1) // bn) * bn
+    lo, hi = _block_runs(valid, n_blocks, be)
 
-    # 1-D int operands trip Mosaic's layout rules; ship per-block rows
-    # as (8, be) tiles (sublane dim must be a multiple of 8) — each
-    # block's ids replicated across the 8 sublanes.
-    seg2d = jnp.repeat(
-        seg_padded.reshape(n_blocks, 1, be), 8, axis=1
-    ).reshape(n_blocks * 8, be)
-    valid2d = jnp.repeat(
-        valid.astype(jnp.int32).reshape(n_blocks, 1, be), 8, axis=1
-    ).reshape(n_blocks * 8, be)
+    # index maps see (grid index, *scalar-prefetch refs); (lo, hi) ride
+    # along for the kernel body only
+    def by_block(i, *_):
+        return (i, 0)
+
+    def by_tile(i, tile, *_):
+        return (tile[i], 0)
+
+    def by_window(i, tile, win, *_):
+        return (win[i], 0)
+
+    def resident(i, *_):
+        return (0, 0)
 
     in_specs = [
-        pl.BlockSpec((8, be), lambda i, tile, win: (i, 0)),
-        pl.BlockSpec((8, be), lambda i, tile, win: (i, 0)),
-        pl.BlockSpec((be, f_in), lambda i, tile, win: (tile[i], 0)),
+        pl.BlockSpec((8, be), by_block),
+        pl.BlockSpec((be, f_in), by_tile),
     ]
-    operands = [seg2d, valid2d, a]
+    operands = [_seg_tiles(seg_padded, n_blocks, be), a]
     if b is not None:
-        in_specs.append(
-            pl.BlockSpec((be, f_in), lambda i, tile, win: (tile[i], 0))
-        )
+        in_specs.append(pl.BlockSpec((be, f_in), by_tile))
         operands.append(b)
     if w is not None:
         # constant index_map: the weight tile stays VMEM-resident
-        in_specs.append(
-            pl.BlockSpec((f_in, f_out), lambda i, tile, win: (0, 0))
-        )
+        in_specs.append(pl.BlockSpec((f_in, f_out), resident))
         operands.append(w)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # (tile_id, window_id) drive index_maps
+        # (tile_id, window_id) drive index_maps; (lo, hi) carry the mask
+        num_scalar_prefetch=4,
         grid=(n_blocks,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bn, f_out), lambda i, tile, win: (win[i], 0)),
+        out_specs=pl.BlockSpec((bn, f_out), by_window),
     )
     kernel = functools.partial(
         _edge_pipeline_kernel,
@@ -470,10 +522,8 @@ def _pallas_edge_pipeline(
         kernel,
         out_shape=jax.ShapeDtypeStruct((n_pad, f_out), jnp.float32),
         grid_spec=grid_spec,
-        # CPU has no Mosaic backend; interpret mode keeps the kernel
-        # differentially testable on the virtual CPU mesh.
-        interpret=jax.default_backend() == "cpu",
-    )(tile_id, window_id, *operands)
+        interpret=_interpret(),
+    )(tile_id, window_id, lo, hi, *operands)
     out_dtype = (
         jnp.promote_types(a.dtype, w.dtype) if w is not None else a.dtype
     )
@@ -491,8 +541,9 @@ def _edge_pipeline_bwd_kernel(
     tile_ref,  # [B] scalar prefetch — input tile per block
     out_tile_ref,  # [B] scalar prefetch — cummax(tile): d_a/d_b output tile
     window_ref,  # [B] scalar prefetch — cotangent window tile per block
+    lo_ref,  # [B] scalar prefetch — first valid slot of the block
+    hi_ref,  # [B] scalar prefetch — one past the last valid slot
     seg_ref,
-    valid_ref,
     g_ref,  # [bn, F_out] cotangent window tile (the gather, as a read)
     a_ref,
     *rest,
@@ -513,10 +564,11 @@ def _edge_pipeline_bwd_kernel(
 
     i = pl.program_id(0)
     node_base = window_ref[i] * bn
-    local = seg_ref[0, :] - node_base  # [be]
+    lane_valid, row_valid = _block_masks(lo_ref[i], hi_ref[i], be)
+    # invalid slots match no window row
+    local = jnp.where(lane_valid, seg_ref[0:1, :] - node_base, -1)  # [1, be]
     rows = jax.lax.broadcasted_iota(jnp.int32, (bn, be), 0)
-    vmask = valid_ref[0, :] != 0
-    onehot = ((local[None, :] == rows) & vmask[None, :]).astype(jnp.float32)
+    onehot = (local == rows).astype(jnp.float32)
     precision = (
         jax.lax.Precision.HIGHEST
         if a_ref.dtype == jnp.float32
@@ -547,10 +599,10 @@ def _edge_pipeline_bwd_kernel(
     a_t = a_ref[:].astype(jnp.float32)
     if has_b:
         b_t = b_ref[:].astype(jnp.float32)
-        da = jnp.where(vmask[:, None], d_h * b_t, 0.0)
-        db = jnp.where(vmask[:, None], d_h * a_t, 0.0)
+        da = jnp.where(row_valid, d_h * b_t, 0.0)
+        db = jnp.where(row_valid, d_h * a_t, 0.0)
     else:
-        da = jnp.where(vmask[:, None], d_h, 0.0)
+        da = jnp.where(row_valid, d_h, 0.0)
 
     # d_a/d_b land at their ALIGNED input tile (perm is a permutation:
     # each edge row owns exactly one slot). Boundary tiles are revisited
@@ -578,7 +630,7 @@ def _edge_pipeline_bwd_kernel(
         # the message recomputed in VMEM from the already-staged tiles,
         # never written to HBM.
         msg = a_t * b_t if has_b else a_t
-        msg = jnp.where(vmask[:, None], msg, 0.0)
+        msg = jnp.where(row_valid, msg, 0.0)
         dw = jax.lax.dot_general(
             msg, slot_g, (((0,), (0,)), ((), ())), precision=precision
         )  # [F_in, F_out]
@@ -632,49 +684,49 @@ def _pallas_edge_pipeline_bwd(
     # tile otherwise reads unspecified rows, and they reach slot_g
     # through the onehot matmul where a select cannot reject them.
     g_pad = jnp.pad(g, ((0, n_pad - num_segments), (0, 0)))
+    lo, hi = _block_runs(valid, n_blocks, be)
 
-    seg2d = jnp.repeat(
-        seg_padded.reshape(n_blocks, 1, be), 8, axis=1
-    ).reshape(n_blocks * 8, be)
-    valid2d = jnp.repeat(
-        valid.astype(jnp.int32).reshape(n_blocks, 1, be), 8, axis=1
-    ).reshape(n_blocks * 8, be)
+    # index maps see (grid index, *scalar-prefetch refs); (lo, hi) ride
+    # along for the kernel body only
+    def by_block(i, *_):
+        return (i, 0)
+
+    def by_tile(i, tile, *_):
+        return (tile[i], 0)
+
+    def by_out_tile(i, tile, otile, *_):
+        return (otile[i], 0)
+
+    def by_window(i, tile, otile, win, *_):
+        return (win[i], 0)
+
+    def resident(i, *_):
+        return (0, 0)
 
     in_specs = [
-        pl.BlockSpec((8, be), lambda i, tile, otile, win: (i, 0)),
-        pl.BlockSpec((8, be), lambda i, tile, otile, win: (i, 0)),
-        pl.BlockSpec((bn, f_out), lambda i, tile, otile, win: (win[i], 0)),
-        pl.BlockSpec((be, f_in), lambda i, tile, otile, win: (tile[i], 0)),
+        pl.BlockSpec((8, be), by_block),
+        pl.BlockSpec((bn, f_out), by_window),
+        pl.BlockSpec((be, f_in), by_tile),
     ]
-    operands = [seg2d, valid2d, g_pad, a]
-    if b is not None:
-        in_specs.append(
-            pl.BlockSpec((be, f_in), lambda i, tile, otile, win: (tile[i], 0))
-        )
-        operands.append(b)
-    if w is not None:
-        in_specs.append(
-            pl.BlockSpec((f_in, f_out), lambda i, tile, otile, win: (0, 0))
-        )
-        operands.append(w)
-    out_specs = [
-        pl.BlockSpec((be, f_in), lambda i, tile, otile, win: (otile[i], 0)),
-    ]
+    operands = [_seg_tiles(seg_padded, n_blocks, be), g_pad, a]
+    out_specs = [pl.BlockSpec((be, f_in), by_out_tile)]
     out_shapes = [jax.ShapeDtypeStruct((t_tiles * be, f_in), jnp.float32)]
     if b is not None:
-        out_specs.append(
-            pl.BlockSpec((be, f_in), lambda i, tile, otile, win: (otile[i], 0))
-        )
+        in_specs.append(pl.BlockSpec((be, f_in), by_tile))
+        operands.append(b)
+        out_specs.append(pl.BlockSpec((be, f_in), by_out_tile))
         out_shapes.append(
             jax.ShapeDtypeStruct((t_tiles * be, f_in), jnp.float32)
         )
     if w is not None:
-        out_specs.append(
-            pl.BlockSpec((f_in, f_out), lambda i, tile, otile, win: (0, 0))
-        )
+        in_specs.append(pl.BlockSpec((f_in, f_out), resident))
+        operands.append(w)
+        out_specs.append(pl.BlockSpec((f_in, f_out), resident))
         out_shapes.append(jax.ShapeDtypeStruct((f_in, f_out), jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # (tile_id, out_tile, window_id)
+        # (tile_id, out_tile, window_id) drive index_maps; (lo, hi)
+        # carry the mask
+        num_scalar_prefetch=5,
         grid=(n_blocks,),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -690,8 +742,8 @@ def _pallas_edge_pipeline_bwd(
         kernel,
         out_shape=tuple(out_shapes),
         grid_spec=grid_spec,
-        interpret=jax.default_backend() == "cpu",
-    )(tile_id, out_tile, window_id, *operands)
+        interpret=_interpret(),
+    )(tile_id, out_tile, window_id, lo, hi, *operands)
     d_a = outs[0][:e].astype(a.dtype)
     d_b = outs[1][:e].astype(b.dtype) if b is not None else None
     d_w = outs[-1].astype(w.dtype) if w is not None else None

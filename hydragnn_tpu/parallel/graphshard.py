@@ -54,22 +54,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from hydragnn_tpu.ops.rbf import cosine_cutoff, gaussian_smearing
 
 
-def _resolve_shard_map():
-    """Version-tolerant shard_map accessor: newer jax exports it at the
-    top level, 0.4.x keeps it in jax.experimental.shard_map. The seed
-    called ``jax.shard_map`` directly and broke every graph-sharding
-    test on jax 0.4.37 — graftlint's jax-api rule now guards this
-    pattern (getattr probes are its sanctioned escape hatch)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map
-
-
-shard_map = _resolve_shard_map()
-
 AXIS = "graph"
 
 
@@ -391,7 +375,7 @@ def halo_mpnn_forward(
     caps, hops = shards.caps, shards.hops
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(),) + (P(AXIS),) * 7,
         out_specs=P(),
@@ -591,7 +575,7 @@ def sharded_mpnn_forward(
     n_shards = int(mesh.shape[AXIS])
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(
             P(),  # params replicated

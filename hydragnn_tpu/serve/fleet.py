@@ -2,11 +2,11 @@
 routing front (docs/SERVING.md "Fleet tier").
 
 ``ServingTier`` is the production shape of the single-engine serving
-story: N engine replicas — THREADS locally, because jax 0.4.37 on CPU
-has no cross-process XLA and every computation must stay process-local
-(the same caveat the fleet-observability drill works under; a real
-multi-host deployment runs one tier process per host and fronts them
-with an external balancer) — each with its own ``DynamicBatcher``, its
+story: N engine replicas — THREADS of one process, each computation
+process-local (a chip belongs to one process at a time, so replicas
+that each own a device share the process that holds the devices; a
+real multi-host deployment runs one tier process per host and fronts
+them with an external balancer) — each with its own ``DynamicBatcher``, its
 own dispatch-loop pump, its own per-replica telemetry shard with
 heartbeats, all behind one ``Router`` (serve/router.py: least-loaded /
 spec-affinity dispatch, deadline-class load shedding).

@@ -13,8 +13,9 @@ PR's determinism contract into a recovery guarantee:
   the global gradient norm, and SELECTS the committed state — the
   freshly-updated tree when the predicate holds, the pre-step tree when
   it fails (``optax.apply_if_finite`` semantics, expressed as a
-  tree-level ``jnp.where`` so the optimizer state keeps its exact
-  structure). A poisoned batch becomes a no-op step even inside a
+  tree-level ``lax.cond`` whose branches only pass a tree through, so
+  the optimizer state keeps its exact structure). A poisoned batch
+  becomes a no-op step even inside a
   ``[K, ...]`` superstep macro that commits K steps atomically, because
   the select runs in the scan body per inner step. The masked metric
   contributions (loss/tasks/graph-weight zeroed on a bad step) make the
@@ -25,7 +26,14 @@ PR's determinism contract into a recovery guarantee:
   disabled (tests/test_guard.py pins this through serial, pipeline and
   superstep feeds; fold_step_metrics' fusion-fence discipline is
   untouched because the select feeds the scan's ys, never the
-  accumulation body).
+  accumulation body). The STATE is committed through a conditional,
+  not a select: a select fuses into the optimizer's update fusions, and
+  on XLA:CPU the fused ``select(ok, b1*mu + (1-b1)*g, mu)`` contracts a
+  different multiply into its FMA than the unguarded ``b1*mu +
+  (1-b1)*g`` does (seen in the dp step's Adam moments, for the leaves
+  whose gradient reaches the update through a layout-changing bitcast)
+  — a conditional is a fusion fence no backend crosses, so the update
+  fusions of the two builds are the same computation.
 
 - **Zero added host-syncs by default**: the per-step predicate and
   grad norm travel as DEFERRED device refs held by ``GuardMonitor``
@@ -206,7 +214,7 @@ def poison_tree(rules: Dict[str, List[int]], site: str, step_counter, tree):
     """NaN every float leaf of ``tree`` (the gradient pytree) at the
     armed steps — same select-not-add discipline as poison_scalar.
 
-    CAVEAT (measured on XLA:CPU, jax 0.4.37): wrapping the gradient
+    CAVEAT (measured on XLA:CPU): wrapping the gradient
     leaves in a select changes how XLA fuses the backward pass with
     the optimizer arithmetic, and LLVM's fp-contract decisions move
     with the fusion boundaries — an armed-but-untriggered ``grad``
@@ -265,9 +273,10 @@ def guarded_commit(old_state, new_state, tot, tasks, grads):
       finiteness predicate over both failure surfaces (a bf16 overflow
       can blow the grads while the loss still reads finite, and vice
       versa for a poisoned label);
-    - ``committed`` is ``new_state`` when ok else ``old_state``
-      leaf-for-leaf (``jnp.where`` — an exact passthrough on the taken
-      side, so a healthy run's params are bitwise the unguarded run's;
+    - ``committed`` is ``new_state`` when ok else ``old_state``, whole
+      trees through a ``lax.cond`` (an exact passthrough, and a fusion
+      fence: the optimizer's update fusions stay the unguarded build's,
+      so a healthy run's params are bitwise the unguarded run's;
       optimizer state, BN stats and the Adam count all stay untouched
       on a skip, matching ``optax.apply_if_finite``), with ``step``
       ALWAYS advanced — fault/telemetry step addressing must tick once
@@ -283,8 +292,8 @@ def guarded_commit(old_state, new_state, tot, tasks, grads):
 
     gnorm = optax.global_norm(grads)
     ok = jnp.isfinite(tot) & jnp.isfinite(gnorm)
-    committed = jax.tree_util.tree_map(
-        lambda n, o: jnp.where(ok, n, o), new_state, old_state
+    committed = jax.lax.cond(
+        ok, lambda n, o: n, lambda n, o: o, new_state, old_state
     )
     committed = committed.replace(step=old_state.step + 1)
     tot_m = jnp.where(ok, tot, jnp.zeros_like(tot))

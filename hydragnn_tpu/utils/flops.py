@@ -24,13 +24,14 @@ roofline ceiling ``min(peak_flops, intensity * peak_bw)`` turns into
 a memory-bound/compute-bound verdict (tools/graftboard.py roofline).
 
 Peak resolution (``resolve_peak_flops`` / ``resolve_peak_bandwidth``):
-the running chip's ``device_kind`` when the tables know it; otherwise
-the ROOFLINE anchor device parsed from ``ROOFLINE_TPU.txt`` (the
-capture the repo's roofline work is normalized against), flagged as
-such — so a CPU debug run still reports "MFU this run would achieve
-on the anchor TPU", keeping the BENCH_TPU 8.35%/0.29% numbers
-continuously observable instead of one-off. Never fabricated: when
-neither resolves, callers get (None, None) and must omit the metric.
+the running chip's ``device_kind`` when the tables know it. A chip the
+tables do not know is an ERROR, never a default — its utilization
+would be computed against some other chip's peak. Only a CPU run (or
+one with no backend up yet) falls back to the ROOFLINE anchor device
+parsed from ``ROOFLINE_TPU.txt``, flagged ``roofline_anchor`` — a
+what-if "MFU this run would achieve on the anchor TPU" that is never
+a device metric; when the anchor is absent too, callers get
+(None, None) and must omit the metric.
 """
 
 from __future__ import annotations
@@ -101,17 +102,29 @@ def roofline_anchor(path: Optional[str] = None) -> Optional[dict]:
     return anchor
 
 
+def _on_accelerator(device_kind: Optional[str]) -> bool:
+    """True for the kind of a real chip; False for a CPU run or one
+    whose backend is not up (kind None) — the only callers that may
+    see the ROOFLINE anchor's what-if peaks."""
+    return device_kind is not None and device_kind.lower() != "cpu"
+
+
 def resolve_peak_flops(
     device_kind: Optional[str] = None,
 ) -> Tuple[Optional[float], Optional[str]]:
     """(peak bf16 FLOPs/sec, basis) for MFU denominators. Basis
-    ``"device"`` = the running chip is in the peak table (a real MFU);
-    ``"roofline_anchor"`` = fell back to ROOFLINE_TPU.txt's device (a
-    what-if utilization on the anchor chip — CPU debug runs report
-    this so the metric stays comparable across hosts); (None, None)
-    when neither resolves."""
-    if device_kind is not None and device_kind in PEAK_FLOPS:
+    ``"device"`` = the running chip is in the peak table (a real MFU).
+    A chip that is NOT in the table raises. On a CPU run the basis is
+    ``"roofline_anchor"`` = ROOFLINE_TPU.txt's device (a what-if
+    utilization on the anchor chip, labelled as such), or (None, None)
+    when the anchor does not resolve."""
+    if device_kind in PEAK_FLOPS:
         return PEAK_FLOPS[device_kind], "device"
+    if _on_accelerator(device_kind):
+        raise ValueError(
+            f"no peak FLOP/s known for device kind {device_kind!r}: add "
+            "it to hydragnn_tpu.utils.flops.PEAK_FLOPS with its source"
+        )
     anchor = roofline_anchor()
     if anchor is not None and anchor["device_kind"] in PEAK_FLOPS:
         return PEAK_FLOPS[anchor["device_kind"]], "roofline_anchor"
@@ -123,13 +136,19 @@ def resolve_peak_bandwidth(
 ) -> Tuple[Optional[float], Optional[str]]:
     """(peak HBM bytes/sec, basis) — the bandwidth axis of the
     roofline. Basis semantics mirror ``resolve_peak_flops``:
-    ``"device"`` = the running chip is in the table;
-    ``"roofline_anchor"`` = ROOFLINE_TPU.txt's device (its own
-    measured ``peak HBM`` header wins over the table when present);
-    (None, None) when neither resolves — callers OMIT the ceiling,
-    never estimate one."""
-    if device_kind is not None and device_kind in PEAK_HBM_BYTES_PER_SEC:
+    ``"device"`` = the running chip is in the table; a chip that is
+    not raises; on a CPU run ``"roofline_anchor"`` = ROOFLINE_TPU.txt's
+    device (its own measured ``peak HBM`` header wins over the table
+    when present), or (None, None) when that does not resolve —
+    callers OMIT the ceiling, never estimate one."""
+    if device_kind in PEAK_HBM_BYTES_PER_SEC:
         return PEAK_HBM_BYTES_PER_SEC[device_kind], "device"
+    if _on_accelerator(device_kind):
+        raise ValueError(
+            f"no peak HBM bandwidth known for device kind {device_kind!r}: "
+            "add it to hydragnn_tpu.utils.flops.PEAK_HBM_BYTES_PER_SEC "
+            "with its source"
+        )
     anchor = roofline_anchor()
     if anchor is not None:
         if anchor.get("hbm_peak_gbps"):

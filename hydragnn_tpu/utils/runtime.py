@@ -15,22 +15,34 @@ from typing import Optional
 
 
 _COMPILE_CACHE_PATH: list = []
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+_DEFAULT_CACHE_DIR = os.path.join(_CHECKOUT, ".xla_cache")
 
 
 def maybe_enable_compilation_cache() -> Optional[str]:
-    """Persistent XLA compilation cache (``HYDRAGNN_TPU_COMPILE_CACHE=
-    <dir>``): jitted executables are serialized to disk and reloaded by
-    later processes, so repeat runs of the same configs (bench
-    invocations, HPO trials, resumed jobs) skip the 20-40s TPU
-    compiles. Idempotent; returns the cache dir when enabled. The
-    reference has no analog (torch recompiles eagerly per process);
-    this is the XLA-native counterpart of its warm-start concerns.
+    """Persistent XLA compilation cache: jitted executables are
+    serialized to disk and reloaded by later processes, so repeat runs
+    of the same configs (bench invocations, HPO trials, resumed jobs)
+    skip the TPU compiles. Returns the cache dir when one is live.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax itself reads it and
+    that directory is the cache: nothing here touches the setting. Where
+    it is not set and the platform is a TPU, the cache is the fixed
+    ``<checkout>/.xla_cache`` (the path is part of the cache key, so a
+    directory that moves never hits). On CPU it stays off: XLA:CPU
+    entries are machine-feature-fingerprinted and reloading them on
+    another host warns of a possible SIGILL. Idempotent.
     """
-    path = os.environ.get("HYDRAGNN_TPU_COMPILE_CACHE", "").strip()
-    if not path:
-        return None
     import jax
 
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if placed:
+        return placed
+    if jax.default_backend() != "tpu":
+        return None
+    path = _DEFAULT_CACHE_DIR
     os.makedirs(path, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", path)
     # jax initializes its persistent-cache module AT MOST ONCE, on the
@@ -47,18 +59,7 @@ def maybe_enable_compilation_cache() -> Optional[str]:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     # ... but bound the disk footprint (LRU eviction) — an unpruned
     # repo-local cache would otherwise grow without limit across runs.
-    try:
-        jax.config.update(
-            "jax_compilation_cache_max_size",
-            int(
-                os.environ.get(
-                    "HYDRAGNN_TPU_COMPILE_CACHE_MAX_BYTES",
-                    str(4 * 1024**3),
-                )
-            ),
-        )
-    except Exception:
-        pass  # older jax without the size knob
+    jax.config.update("jax_compilation_cache_max_size", 4 * 1024**3)
     return path
 
 
@@ -68,15 +69,10 @@ def reset_compilation_cache() -> None:
     the current config. The ONE copy of the reset grammar — used by
     ``maybe_enable_compilation_cache`` and by tests restoring pristine
     state."""
-    _COMPILE_CACHE_PATH.clear()
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc,
-        )
+    from jax.experimental.compilation_cache import compilation_cache
 
-        _cc.reset_cache()
-    except Exception:
-        pass  # older jax without the reset API
+    _COMPILE_CACHE_PATH.clear()
+    compilation_cache.reset_cache()
 
 
 def job_end_time() -> Optional[float]:
@@ -188,8 +184,8 @@ def memory_stats() -> dict:
     (TPU runtime does; CPU returns {}). Reference print_peak_memory.
 
     Hardened for telemetry use (docs/OBSERVABILITY.md ``memory``
-    rows): a backend whose ``memory_stats()`` RAISES (older libtpu,
-    PJRT plugins mid-teardown, non-addressable devices in multi-host
+    rows): a backend whose ``memory_stats()`` RAISES (a device
+    mid-teardown, non-addressable devices in multi-host
     meshes) or reports only a subset of the allocator keys degrades to
     a partial/empty dict — live memory telemetry must never be able
     to kill a run. Only keys the allocator actually reported appear
@@ -206,7 +202,7 @@ def memory_stats() -> dict:
             stats = getattr(d, "memory_stats", None)
             s = stats() if callable(stats) else None
         except Exception:
-            continue  # older libtpu raises instead of returning None
+            continue  # a device mid-teardown raises instead of returning None
         if not s:
             continue
         entry = {}

@@ -270,8 +270,8 @@ def _self_description() -> dict:
     what-if on the ROOFLINE anchor, and says so). Device fields appear
     only when a jax backend is ALREADY initialized
     (``_jax_backend_initialized``) — constructing a stream must never
-    initialize one. Best-effort throughout — a partial header beats
-    no stream."""
+    initialize one. Host and device facts are best-effort — a partial
+    header beats no stream."""
     out: Dict[str, Any] = {}
     try:
         import socket
@@ -294,22 +294,21 @@ def _self_description() -> dict:
             out["process_count"] = jax.process_count()
         except Exception:
             pass
-    try:
-        from hydragnn_tpu.utils.flops import (
-            resolve_peak_bandwidth,
-            resolve_peak_flops,
-        )
+    from hydragnn_tpu.utils.flops import (
+        resolve_peak_bandwidth,
+        resolve_peak_flops,
+    )
 
-        peak, basis = resolve_peak_flops(device_kind)
-        if peak:
-            out["peak_flops"] = peak
-            out["peak_basis"] = basis
-        bw, bw_basis = resolve_peak_bandwidth(device_kind)
-        if bw:
-            out["peak_hbm_bytes_per_sec"] = bw
-            out["peak_hbm_basis"] = bw_basis
-    except Exception:
-        pass
+    # not best-effort: a chip the peak tables do not know raises here,
+    # before any row could be read against another chip's peaks
+    peak, basis = resolve_peak_flops(device_kind)
+    if peak:
+        out["peak_flops"] = peak
+        out["peak_basis"] = basis
+    bw, bw_basis = resolve_peak_bandwidth(device_kind)
+    if bw:
+        out["peak_hbm_bytes_per_sec"] = bw
+        out["peak_hbm_basis"] = bw_basis
     return out
 
 

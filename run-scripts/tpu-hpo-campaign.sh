@@ -9,8 +9,9 @@
 # natural launch is N queued-resource VMs, each taking a strided slice
 # of the deterministically-shuffled search grid (--worker i
 # --num-workers N in the driver) — a true partition, no duplicated
-# trials. The persistent compile cache (HYDRAGNN_TPU_COMPILE_CACHE)
-# makes repeat architectures reload executables instead of recompiling.
+# trials. The persistent compile cache (on a TPU: <checkout>/.xla_cache,
+# or wherever JAX_COMPILATION_CACHE_DIR points) makes repeat
+# architectures reload executables instead of recompiling.
 #
 # Usage:
 #   TPU_PREFIX=hpo-worker N_WORKERS=4 ZONE=us-east5-a \
@@ -31,7 +32,6 @@ for i in $(seq 0 $((N_WORKERS - 1))); do
   gcloud compute tpus tpu-vm ssh "${TPU_PREFIX}-${i}" --zone "$ZONE" \
     --command "
       cd ~/hydragnn_tpu_repo &&
-      HYDRAGNN_TPU_COMPILE_CACHE=~/.hydragnn_xla_cache \
       python $DRIVER$ARGS --worker ${i} --num-workers ${N_WORKERS} \
         2>&1 | tee hpo_worker_${i}.log
     " &

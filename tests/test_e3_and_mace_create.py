@@ -25,7 +25,7 @@ def test_sh_basis_np_matches_runtime_sh_basis(l):
     v = rng.normal(size=(64, 3))
     v /= np.linalg.norm(v, axis=1, keepdims=True)
     want = _sh_basis_np(v, l)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(
             sh_basis(np.asarray(v, np.float64), l, normalize=False)
         )[:, l * l : (l + 1) * (l + 1)]
@@ -141,6 +141,6 @@ def test_wigner_d_fit_is_fp64_regardless_of_rot_dtype(l):
         # fit itself stays fp64, so the result matches to that level.
         assert np.abs(got - want).max() < 1e-5
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         got = wigner_d_from_sh(l, jnp.asarray(rot64))
         assert np.array_equal(got, want)  # fp64 in, bitwise-equal fit

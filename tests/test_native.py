@@ -147,3 +147,29 @@ def test_sample_store_shared_memory():
     # after the owner closes, the shm name must be gone
     with pytest.raises(RuntimeError):
         SampleStore.attach(name)
+
+
+def test_build_is_keyed_on_source_hash(monkeypatch, tmp_path):
+    """A binary built from other sources is never loaded: the library's
+    name carries a hash of the two .cpp files, an old-style or stale
+    ``libhgtpu_native*.so`` next to them is ignored and swept, and an
+    edited source builds under a new name."""
+    import shutil
+
+    from hydragnn_tpu.native import bindings
+
+    for name in ("celllist.cpp", "samplestore.cpp"):
+        shutil.copy(os.path.join(bindings._HERE, name), tmp_path / name)
+    stale = tmp_path / "libhgtpu_native.so"
+    stale.write_bytes(b"not a shared object, and newer than the sources")
+    monkeypatch.setattr(bindings, "_HERE", str(tmp_path))
+
+    assert bindings._build() is not None
+    built = sorted(p.name for p in tmp_path.glob("libhgtpu_native*.so"))
+    assert len(built) == 1 and built[0] != stale.name
+
+    with open(tmp_path / "celllist.cpp", "a") as f:
+        f.write("\n// edited\n")
+    assert bindings._build() is not None
+    rebuilt = sorted(p.name for p in tmp_path.glob("libhgtpu_native*.so"))
+    assert len(rebuilt) == 1 and rebuilt != built

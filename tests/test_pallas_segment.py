@@ -429,7 +429,9 @@ def test_edge_pipeline_masked_edges():
     rng = np.random.default_rng(29)
     e, n, f = 1100, 128, 32
     seg = np.sort(rng.integers(0, n, e)).astype(np.int32)
-    ev = rng.random(e) < 0.7
+    # collate's mask: padding edges sort after every real one, so each
+    # block's valid rows stay one contiguous run (the kernel contract)
+    ev = np.arange(e) < 770
     a = jnp.asarray(rng.normal(size=(e, f)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(e, f)), jnp.float32)
     plan = plan_blocks_static(
@@ -451,6 +453,19 @@ def test_edge_pipeline_masked_edges():
         lambda x: jnp.sum(edge_pipeline_planned(x, b, None, *plan, n) ** 2)
     )(a)
     assert np.all(np.asarray(g)[~ev] == 0.0)
+
+
+def test_plan_rejects_edge_mask_with_holes():
+    """The kernels rebuild a block's mask from the two end points of
+    its valid run, so a mask that leaves holes inside a run cannot be
+    planned — it raises at plan time instead of summing masked rows."""
+    from hydragnn_tpu.ops.pallas_segment import plan_sorted_blocks
+
+    seg = np.sort(np.random.default_rng(3).integers(0, 64, 900))
+    ev = np.ones(900, bool)
+    ev[100] = False
+    with pytest.raises(ValueError, match="holes"):
+        plan_sorted_blocks(seg.astype(np.int32), 64, edge_valid=ev)
 
 
 def test_edge_pipeline_empty_windows_and_static_padding():
@@ -870,7 +885,7 @@ def test_fused_bwd_masked_edges_and_static_padding(monkeypatch):
     rng = np.random.default_rng(53)
     e, n, fi, fo = 1100, 2048, 32, 16  # ids in [0, 60): empty windows +
     seg = np.sort(rng.integers(0, 60, e)).astype(np.int32)  # padding
-    ev = rng.random(e) < 0.7
+    ev = np.arange(e) < 770  # real edges first, as collate lays them
     a = jnp.asarray(rng.normal(size=(e, fi)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(e, fi)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(fi, fo)), jnp.float32)

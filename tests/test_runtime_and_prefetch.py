@@ -340,28 +340,65 @@ def test_dump_testdata_env(tmp_path, monkeypatch):
     assert data["true_0"].shape[0] == 6
 
 
-def test_compilation_cache_env(monkeypatch, tmp_path):
-    """HYDRAGNN_TPU_COMPILE_CACHE=<dir> turns on jax's persistent
-    compilation cache and populates it through run_training — INCLUDING
-    in a process that already compiled something beforehand (jax
-    latches the cache module as "initialized, disabled" on the first
-    compile; maybe_enable_compilation_cache must reset the latch, else
-    this test passes standalone and fails after any earlier test)."""
+def test_compilation_cache_placed_from_outside(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: jax reads it itself, that
+    directory is the cache, and the program sets no other."""
     import jax
 
     from hydragnn_tpu.utils import runtime as rt
 
-    monkeypatch.delenv("HYDRAGNN_TPU_COMPILE_CACHE", raising=False)
-    assert rt.maybe_enable_compilation_cache() is None
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    # even where the default would apply, the placed directory wins
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(rt, "_DEFAULT_CACHE_DIR", str(tmp_path / "default"))
+    before = jax.config.jax_compilation_cache_dir
+    assert rt.maybe_enable_compilation_cache() == placed
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / "default").exists()
 
+
+def test_compilation_cache_off_on_cpu(monkeypatch):
+    """Variable unset on an explicit CPU run: the cache stays off."""
+    import jax
+
+    from hydragnn_tpu.utils import runtime as rt
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    assert rt.maybe_enable_compilation_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not rt._COMPILE_CACHE_PATH
+
+
+def test_compilation_cache_default_dir_on_tpu(monkeypatch, tmp_path):
+    """Variable unset on a TPU: the cache is the fixed
+    ``<checkout>/.xla_cache`` and is populated through a compile —
+    INCLUDING in a process that already compiled something beforehand
+    (jax latches the cache module as "initialized, disabled" on the
+    first compile; maybe_enable_compilation_cache must reset the latch,
+    else this test passes standalone and fails after any earlier
+    test)."""
+    import jax
+
+    from hydragnn_tpu.utils import runtime as rt
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert rt._DEFAULT_CACHE_DIR == os.path.join(repo, ".xla_cache")
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     # Latch the cache module the way a real process does: one compile
     # before the cache dir is configured (order-independence guard).
     jax.jit(lambda x: x - 1.0)(jax.numpy.zeros(())).block_until_ready()
 
+    # the platform is steered here, and the default is pointed at a
+    # scratch dir so CPU executables never land in the checkout's cache
     cache_dir = str(tmp_path / "xla_cache")
-    monkeypatch.setenv("HYDRAGNN_TPU_COMPILE_CACHE", cache_dir)
+    monkeypatch.setattr(rt, "_DEFAULT_CACHE_DIR", cache_dir)
     try:
-        assert rt.maybe_enable_compilation_cache() == cache_dir
+        with monkeypatch.context() as on_tpu:
+            on_tpu.setattr(jax, "default_backend", lambda: "tpu")
+            assert rt.maybe_enable_compilation_cache() == cache_dir
         assert jax.config.jax_compilation_cache_dir == cache_dir
 
         @jax.jit
@@ -375,6 +412,7 @@ def test_compilation_cache_env(monkeypatch, tmp_path):
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs", 1.0
         )
+        jax.config.update("jax_compilation_cache_max_size", -1)
         # Back to pristine: drop the handle on the tmp dir so later
         # tests (and their compiles) see an uninitialized cache module.
         rt.reset_compilation_cache()

@@ -627,9 +627,24 @@ def test_resolve_peak_bandwidth_anchor_and_device():
 
     bw, basis = resolve_peak_bandwidth("TPU v4")
     assert bw == PEAK_HBM_BYTES_PER_SEC["TPU v4"] and basis == "device"
-    # unknown kind -> ROOFLINE_TPU.txt anchor (its measured header)
+    # explicit CPU run -> ROOFLINE_TPU.txt anchor (its measured header)
     bw, basis = resolve_peak_bandwidth("cpu")
     assert basis == "roofline_anchor" and bw == 819.0e9
+
+
+@pytest.mark.parametrize("kind", ["TPU v9 mega", "NVIDIA Z100"])
+def test_resolve_peaks_raise_for_a_chip_not_in_the_table(kind):
+    """A real chip the tables do not know is an error, never the
+    anchor chip's peaks under another device's name."""
+    from hydragnn_tpu.utils.flops import (
+        resolve_peak_bandwidth,
+        resolve_peak_flops,
+    )
+
+    with pytest.raises(ValueError, match="PEAK_FLOPS"):
+        resolve_peak_flops(kind)
+    with pytest.raises(ValueError, match="PEAK_HBM_BYTES_PER_SEC"):
+        resolve_peak_bandwidth(kind)
 
 
 def _exec_rows(rows):

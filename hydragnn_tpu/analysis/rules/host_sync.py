@@ -115,6 +115,15 @@ HOT_SEEDS = (
     ("utils/telemetry.py", "memory_row"),
     ("utils/tracer.py", "note_trace_step"),
     ("utils/tracer.py", "step_annotation"),
+    # The host spans of ISSUE 27: region/span open and close around
+    # every site of the loop's host work (feed wait, clock record, step
+    # hook, epoch fetch) and on the feed's threads, per dispatch; scope
+    # runs at trace time inside every jitted step. None may sync.
+    ("utils/tracer.py", "region"),
+    ("utils/tracer.py", "span"),
+    ("utils/tracer.py", "scope"),
+    ("utils/tracer.py", "_Region.__enter__"),
+    ("utils/tracer.py", "_Region.__exit__"),
     # Fleet observability (ISSUE 14, docs/OBSERVABILITY.md "Fleet
     # observability"): the liveness counters/phase marks run on the
     # feed hot paths (DPLoader/MultiBranchLoader iterators, once per

@@ -488,14 +488,16 @@ def update_config(
             raise ValueError(
                 "Training.Profiling must be an object "
                 '{"enabled": bool, "epoch": int, "steps": int, '
-                '"trace_dir": str}'
+                '"trace_dir": str, "python_tracer": bool}'
             )
-        unknown = set(prof) - {"enabled", "epoch", "steps", "trace_dir"}
+        unknown = set(prof) - {
+            "enabled", "epoch", "steps", "trace_dir", "python_tracer"
+        }
         if unknown:
             raise ValueError(
                 "Training.Profiling: unknown keys "
                 f"{sorted(unknown)} (accepted: enabled, epoch, steps, "
-                "trace_dir)"
+                "trace_dir, python_tracer)"
             )
 
     training.setdefault("conv_checkpointing", False)

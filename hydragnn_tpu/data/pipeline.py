@@ -57,6 +57,7 @@ import numpy as np
 
 from hydragnn_tpu.data.graph import GraphBatch, MacroBatch, PadSpec, collate
 from hydragnn_tpu.data.prefetch import _pin_affinity
+from hydragnn_tpu.utils import tracer as tr
 
 __all__ = [
     "PipelineStats",
@@ -138,7 +139,6 @@ class PipelineStats:
         reads the same counters. Idempotent-ish: called per epoch,
         each call contributes one sample per metric."""
         from hydragnn_tpu.utils import telemetry
-        from hydragnn_tpu.utils import tracer as tr
 
         if telemetry.active():
             telemetry.emit({"t": "pipeline", **self.as_dict()})
@@ -1060,12 +1060,17 @@ class ParallelPipelineLoader:
             for group in groups:
                 if stop.is_set():
                     break
-                items.append(self._collate_group(ds, loader, group))
+                # spans of the feed's threads on the profiler's clock
+                # (no-ops without a live capture): what the feed was
+                # doing while the loop waited in <region>/feed_wait
+                with tr.span("feed/collate"):
+                    items.append(self._collate_group(ds, loader, group))
                 if items[-1][0] == "err":
                     break  # later batches of the chunk are unreachable
             if self.to_device:
                 try:
-                    items = self._transfer_chunk(items)
+                    with tr.span("feed/h2d"):
+                        items = self._transfer_chunk(items)
                 except BaseException as e:
                     # A failed transfer must still post the chunk, or
                     # the consumer would wait on it forever while other
@@ -1232,11 +1237,12 @@ class ParallelPipelineLoader:
         import jax
 
         t0 = time.perf_counter()
-        out = (
-            jax.device_put(batch, self.device)
-            if self.device is not None
-            else jax.device_put(batch)
-        )
+        with tr.span("feed/h2d"):
+            out = (
+                jax.device_put(batch, self.device)
+                if self.device is not None
+                else jax.device_put(batch)
+            )
         self.stats.record_h2d(time.perf_counter() - t0)
         return out
 

@@ -17,6 +17,8 @@ from typing import Iterator, Optional, Sequence
 
 import jax
 
+from hydragnn_tpu.utils import tracer as tr
+
 
 def _pin_affinity(offset: int, width: int) -> None:
     """Pin the worker thread to a CPU range (reference
@@ -104,14 +106,22 @@ class PrefetchLoader:
             if self.affinity_offset is not None:
                 _pin_affinity(self.affinity_offset, self.affinity_width)
             try:
-                for batch in self.loader:
+                it = iter(self.loader)
+                while True:
+                    # the feed thread's work as spans on the profiler's
+                    # clock (no-ops without a live capture)
+                    with tr.span("feed/collate"):
+                        batch = next(it, _SENTINEL)
+                    if batch is _SENTINEL:
+                        break
                     if stop.is_set():
                         return
                     if self.to_device:
-                        if self.device is not None:
-                            batch = jax.device_put(batch, self.device)
-                        else:
-                            batch = jax.device_put(batch)
+                        with tr.span("feed/h2d"):
+                            if self.device is not None:
+                                batch = jax.device_put(batch, self.device)
+                            else:
+                                batch = jax.device_put(batch)
                     if not stop_aware_put(batch):
                         return
             except BaseException as e:  # surface worker errors

@@ -38,8 +38,10 @@ from hydragnn_tpu.models.layers import (
 from hydragnn_tpu.models.spec import ModelConfig
 from hydragnn_tpu.ops import segment_max, segment_mean, segment_sum
 from hydragnn_tpu.ops.segment import aggregate_receivers_pipeline
+from hydragnn_tpu.utils import tracer as tr
 
 
+@tr.scoped("pool")
 def graph_pool(
     x: jax.Array, batch: GraphBatch, mode: str
 ) -> jax.Array:
@@ -158,9 +160,10 @@ class ConvNodeHead(nn.Module):
             w_n, _ = DenseParams(d, use_bias=False, name=f"neigh_{i}")(
                 x.shape[-1]
             )
-            neigh = aggregate_receivers_pipeline(
-                x[batch.senders], None, batch, weight=w_n, mean=True
-            )
+            with tr.scope("edge_aggregate"):  # the sender gather too
+                neigh = aggregate_receivers_pipeline(
+                    x[batch.senders], None, batch, weight=w_n, mean=True
+                )
             x = nn.Dense(d, name=f"self_{i}")(x) + neigh
             x = MaskedBatchNorm(name=f"bn_{i}")(x, bn_mask, train=train)
             if not last:

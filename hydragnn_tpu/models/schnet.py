@@ -29,6 +29,7 @@ from hydragnn_tpu.ops import (
     segment_sum,
 )
 from hydragnn_tpu.ops.segment import aggregate_receivers_pipeline
+from hydragnn_tpu.utils import tracer as tr
 
 
 class CFConv(nn.Module):
@@ -100,8 +101,11 @@ class CFConv(nn.Module):
         # can ride inside the kernel; the bias adds after the reduce
         # (segment-sum and matmul commute; the bias does not).
         w2, b2 = DenseParams(self.out_dim, name="lin2")(self.num_filters)
-        out = aggregate_receivers_pipeline(h[snd], W, batch, weight=w2) + b2
-        return out, pos
+        # the scope holds the sender gather too: the block's boundary
+        # is the algorithm's, whatever kernel implements it
+        with tr.scope("edge_aggregate"):
+            out = aggregate_receivers_pipeline(h[snd], W, batch, weight=w2)
+        return out + b2, pos
 
 
 class SchNetStack(nn.Module):

@@ -523,6 +523,9 @@ def _pallas_edge_pipeline(
         out_shape=jax.ShapeDtypeStruct((n_pad, f_out), jnp.float32),
         grid_spec=grid_spec,
         interpret=_interpret(),
+        # a stable kernel name for the trace's readers (the op itself
+        # is a tpu_custom_call whatever the scope around it says)
+        name="edge_pipeline_forward",
     )(tile_id, window_id, lo, hi, *operands)
     out_dtype = (
         jnp.promote_types(a.dtype, w.dtype) if w is not None else a.dtype
@@ -743,6 +746,7 @@ def _pallas_edge_pipeline_bwd(
         out_shape=tuple(out_shapes),
         grid_spec=grid_spec,
         interpret=_interpret(),
+        name="edge_pipeline_pullback",
     )(tile_id, out_tile, window_id, lo, hi, *operands)
     d_a = outs[0][:e].astype(a.dtype)
     d_b = outs[1][:e].astype(b.dtype) if b is not None else None

@@ -17,7 +17,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from hydragnn_tpu.utils import tracer as tr
 
+
+@tr.scoped("edge_geometry")
 def gaussian_smearing(
     dist: jax.Array, start: float, stop: float, num_gaussians: int
 ) -> jax.Array:
@@ -28,6 +31,7 @@ def gaussian_smearing(
     return jnp.exp(coeff * diff**2)
 
 
+@tr.scoped("edge_geometry")
 def bessel_basis(dist: jax.Array, cutoff: float, num_radial: int) -> jax.Array:
     """sqrt(2/c) * sin(n pi d / c) / d — spherical Bessel j0 basis."""
     freq = jnp.arange(1, num_radial + 1, dtype=dist.dtype) * jnp.pi
@@ -37,6 +41,7 @@ def bessel_basis(dist: jax.Array, cutoff: float, num_radial: int) -> jax.Array:
     return prefactor * jnp.sin(freq * d_safe) / (d_safe * cutoff)
 
 
+@tr.scoped("edge_geometry")
 def sinc_basis(dist: jax.Array, cutoff: float, num_basis: int) -> jax.Array:
     """sinc-like expansion sin(n pi d/c)/d used by PaiNN
     (reference: hydragnn/models/PAINNStack.py:331-341)."""
@@ -45,6 +50,7 @@ def sinc_basis(dist: jax.Array, cutoff: float, num_basis: int) -> jax.Array:
     return jnp.sin(n * jnp.pi * d_safe / cutoff) / d_safe
 
 
+@tr.scoped("edge_geometry")
 def chebyshev_basis(dist: jax.Array, cutoff: float, num_basis: int) -> jax.Array:
     """Chebyshev polynomials of scaled distance on [-1, 1]
     (reference: mace_utils/modules/radial.py ChebychevBasis)."""
@@ -53,12 +59,14 @@ def chebyshev_basis(dist: jax.Array, cutoff: float, num_basis: int) -> jax.Array
     return jnp.cos(n * jnp.arccos(x))
 
 
+@tr.scoped("edge_geometry")
 def cosine_cutoff(dist: jax.Array, cutoff: float) -> jax.Array:
     """0.5 (cos(pi d/c) + 1) for d < c else 0 (SchNet/PaiNN cutoff)."""
     out = 0.5 * (jnp.cos(jnp.pi * dist / cutoff) + 1.0)
     return jnp.where(dist < cutoff, out, 0.0)
 
 
+@tr.scoped("edge_geometry")
 def polynomial_cutoff(dist: jax.Array, cutoff: float, p: int = 6) -> jax.Array:
     """MACE polynomial envelope, C^p smooth at the cutoff
     (reference: mace_utils/modules/radial.py PolynomialCutoff)."""
@@ -111,6 +119,7 @@ def soft_transform(
     return dist + 0.5 * jnp.tanh(-x - a * x**b) + 0.5
 
 
+@tr.scoped("edge_geometry")
 def edge_vectors_and_lengths(
     pos: jax.Array,
     senders: jax.Array,

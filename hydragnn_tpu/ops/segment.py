@@ -14,7 +14,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from hydragnn_tpu.utils import tracer as tr
 
+
+@tr.scoped("segment/sum")
 def segment_sum(
     data: jax.Array,
     segment_ids: jax.Array,
@@ -26,6 +29,7 @@ def segment_sum(
     return jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
 
 
+@tr.scoped("segment/mean")
 def segment_mean(
     data: jax.Array,
     segment_ids: jax.Array,
@@ -41,6 +45,7 @@ def segment_mean(
     return total / _bcast_trailing(count, total)
 
 
+@tr.scoped("segment/max")
 def segment_max(
     data: jax.Array,
     segment_ids: jax.Array,
@@ -58,6 +63,7 @@ def segment_max(
     return jnp.where(out <= neg, jnp.asarray(empty_value, out.dtype), out)
 
 
+@tr.scoped("segment/min")
 def segment_min(
     data: jax.Array,
     segment_ids: jax.Array,
@@ -73,6 +79,7 @@ def segment_min(
     return jnp.where(out >= pos, jnp.asarray(empty_value, out.dtype), out)
 
 
+@tr.scoped("segment/std")
 def segment_std(
     data: jax.Array,
     segment_ids: jax.Array,
@@ -88,6 +95,7 @@ def segment_std(
     return jnp.sqrt(var + eps)
 
 
+@tr.scoped("segment/softmax")
 def segment_softmax(
     logits: jax.Array,
     segment_ids: jax.Array,
@@ -238,6 +246,7 @@ def _plan_dispatch(
     )
 
 
+@tr.scoped("edge_aggregate")
 def aggregate_receivers(
     msg: jax.Array, batch, *, use_plan: Optional[bool] = None
 ) -> jax.Array:
@@ -271,6 +280,7 @@ def aggregate_receivers(
     )
 
 
+@tr.scoped("edge_aggregate")
 def aggregate_receivers_product(
     a: jax.Array, b: jax.Array, batch, *, use_plan: Optional[bool] = None
 ) -> jax.Array:
@@ -311,6 +321,7 @@ def aggregate_receivers_product(
     )
 
 
+@tr.scoped("edge_aggregate")
 def aggregate_receivers_pipeline(
     a: jax.Array,
     b: Optional[jax.Array],
@@ -381,6 +392,7 @@ def aggregate_receivers_pipeline(
     return out
 
 
+@tr.scoped("edge_aggregate")
 def aggregate_receivers_mean(
     msg: jax.Array, batch, *, use_plan: Optional[bool] = None
 ) -> jax.Array:
@@ -398,6 +410,7 @@ def aggregate_receivers_mean(
     return total / _bcast_trailing(count, total)
 
 
+@tr.scoped("edge_aggregate")
 def segment_multi_aggregate(
     h: jax.Array,
     batch,

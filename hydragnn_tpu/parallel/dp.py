@@ -194,8 +194,9 @@ def make_dp_train_step(
     loss_over_devices = _weighted_loss_over_devices(device_loss)
     rules = guard_mod.nan_injections()
 
+    # program names as in train/loop.py: one vocabulary on the trace
     @partial(jax.jit, donate_argnums=0)
-    def step(state: TrainState, stacked: GraphBatch):
+    def train_step(state: TrainState, stacked: GraphBatch):
         stacked = guard_mod.poison_batch(rules, state.step, stacked)
         if guard:
             ng = jnp.sum(stacked.graph_mask).astype(jnp.float32)
@@ -215,7 +216,7 @@ def make_dp_train_step(
             return state, tot, tasks, ng, ok, gnorm
         return new_state, tot, tasks
 
-    return step
+    return train_step
 
 
 def make_dp_eval_step(
@@ -242,7 +243,7 @@ def make_dp_eval_step(
     )
 
     @jax.jit
-    def step(state: TrainState, stacked: GraphBatch):
+    def eval_step(state: TrainState, stacked: GraphBatch):
         stacked = cast_batch(stacked, compute_dtype)
         if collect_outputs:
             tots, tasks, outputs = jax.vmap(
@@ -257,7 +258,7 @@ def make_dp_eval_step(
         )
         return tot, task
 
-    return step
+    return eval_step
 
 
 def make_dp_superstep_fn(
@@ -323,7 +324,7 @@ def make_dp_superstep_fn(
         loss_over_devices = _weighted_loss_over_devices(device_loss)
         rules = guard_mod.nan_injections()
 
-        def superstep(state, acc, batches):
+        def train_superstep(state, acc, batches):
             def body(st, stacked):
                 stacked = guard_mod.poison_batch(rules, st.step, stacked)
                 stacked = cast_batch(stacked, compute_dtype)
@@ -357,8 +358,8 @@ def make_dp_superstep_fn(
             return state, fold_step_metrics(acc, tots, tasks, gs)
 
         if donate:
-            return jax.jit(superstep, donate_argnums=(0, 1))
-        return jax.jit(superstep)
+            return jax.jit(train_superstep, donate_argnums=(0, 1))
+        return jax.jit(train_superstep)
 
     device_loss = make_eval_loss_fn(model, cfg, compute_grad_energy)
     eval_over_devices = _weighted_eval_over_devices(device_loss)

@@ -244,7 +244,7 @@ def make_multibranch_train_step(
         return jnp.mean(tots), (jnp.mean(tasks, axis=0), new_bn)
 
     @partial(jax.jit, donate_argnums=0)
-    def _step(state: TrainState, stacked: GraphBatch):
+    def train_step(state: TrainState, stacked: GraphBatch):
         stacked = guard_mod.poison_batch(rules, state.step, stacked)
         if guard:
             ng = jnp.sum(stacked.graph_mask).astype(jnp.float32)
@@ -317,14 +317,14 @@ def make_multibranch_train_step(
         return committed, tot, tasks, ng, ok, gnorm, new_state
 
     if not guard:
-        return _step
+        return train_step
 
     def step(state: TrainState, stacked: GraphBatch):
-        return _step(state, stacked)[:6]
+        return train_step(state, stacked)[:6]
 
     # AOT-lowering hook for the telemetry executable capture
     # (StepClock._maybe_capture lowers the step it dispatched).
-    step.lower = _step.lower
+    step.lower = train_step.lower
     return step
 
 

@@ -485,9 +485,22 @@ def run_training(
 
     Returns (state, model, cfg, history, config).
     """
+    from hydragnn_tpu.utils import telemetry
+
+    # Where the time before the first steady epoch goes, phase by phase:
+    # ``setup`` rows in the telemetry stream (docs/OBSERVABILITY.md).
+    setup = telemetry.SetupClock()
+    try:
+        return _run_training(config_source, datasets, seed, setup)
+    finally:
+        setup.close()
+
+
+def _run_training(config_source, datasets, seed, setup):
     from hydragnn_tpu.parallel import runtime
     from hydragnn_tpu.utils.runtime import maybe_enable_compilation_cache
 
+    setup.phase("config")
     runtime.maybe_initialize_distributed()
     maybe_enable_compilation_cache()
     config = load_config(config_source)
@@ -540,16 +553,15 @@ def run_training(
     save_config(config, log_name)
     config["_log_name"] = log_name
 
-    # HYDRAGNN_TPU_TRACE_LEVEL > 0: install the default tracer set so
-    # the loop's tr.start/stop regions actually record (reference wires
-    # tr.initialize in its drivers; here the runner owns it). The
-    # device-metrics tracer stays inert off-TPU, so it is always safe.
+    # HYDRAGNN_TPU_TRACE_LEVEL > 0: install the region timer so the
+    # loop's tr.region sites actually record (reference wires
+    # tr.initialize in its drivers; here the runner owns it).
     trace_env = os.environ.get("HYDRAGNN_TPU_TRACE_LEVEL", "")
     if trace_env.strip().isdigit() and int(trace_env) > 0:
         from hydragnn_tpu.utils import tracer as tr
 
         if not tr.has("RegionTimer"):
-            tr.initialize(["RegionTimer", "DeviceMetricsTracer"])
+            tr.initialize(["RegionTimer"])
 
     training = config["NeuralNetwork"]["Training"]
     _, compute_dtype = resolve_precision(training.get("precision", "fp32"))
@@ -571,6 +583,7 @@ def run_training(
 
     set_segment_impl_override(seg_impl)
 
+    setup.phase("loaders")  # the packing fit and the pad plans included
     batch_size = int(training.get("batch_size", 32))
     trips = needs_triplets(
         config["NeuralNetwork"]["Architecture"].get("mpnn_type", "SchNet")
@@ -858,6 +871,7 @@ def run_training(
             )
         tx = select_optimizer(training)
 
+    setup.phase("model_init")
     example = next(iter(init_loader))
     params, batch_stats = init_params(model, example, seed=seed)
     n_params = sum(
@@ -871,6 +885,7 @@ def run_training(
 
         print_peak_memory(lambda m: print_distributed(verbosity, 2, m))
 
+    setup.phase("state")  # optimizer state, restore, mesh placement
     state = create_train_state(params, tx, batch_stats)
 
     # "orbax" writes every process's shards directly (no host gather;
@@ -946,11 +961,13 @@ def run_training(
     # guards against).
     from hydragnn_tpu.utils import telemetry
 
+    setup.phase("stream")  # the telemetry stream, the checkpoint writer
     tel_stream = telemetry.configure(
         training,
         log_name=log_name,
         meta={"log_name": log_name, "scheme": plan.scheme},
     )
+    setup.stream_ready()
     if telemetry.active():
         # Run context for the step clock: the model config keys the
         # live MFU rows (utils/flops.model_flops_per_graph), the
@@ -976,6 +993,7 @@ def run_training(
     )
 
     try:
+        setup.end_phase()
         state, hist = train_validate_test(
             model,
             cfg,
@@ -1067,8 +1085,8 @@ def run_training(
         if cfg.enable_interatomic_potential and trues[1].ndim == 2:
             viz.create_parity_plot_vector(trues[1], preds[1], name="forces")
 
-    # Flush tracer regions (timing + device columns on TPU) — the
-    # reference dumps GPTL/region CSVs at the end of its drivers.
+    # Flush tracer regions — the reference dumps GPTL/region CSVs at
+    # the end of its drivers.
     from hydragnn_tpu.utils import tracer as tr
 
     if tr.has("RegionTimer"):

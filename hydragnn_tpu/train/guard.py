@@ -77,6 +77,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
+from hydragnn_tpu.utils import tracer as tr
+
 __all__ = [
     "GuardSettings",
     "guard_settings",
@@ -264,6 +266,7 @@ def poison_batch(rules: Dict[str, List[int]], step_counter, batch):
 # ----------------------------------------------------------------------
 
 
+@tr.scoped("guard")
 def guarded_commit(old_state, new_state, tot, tasks, grads):
     """The guard's traced core: predicate + containment + metric mask.
 

@@ -143,7 +143,9 @@ def make_train_step(
     loss_fn = make_loss_fn(model, cfg, compute_grad_energy)
     rules = guard_mod.nan_injections()
 
-    def step(state: TrainState, batch: GraphBatch):
+    # the jitted programs' names tell train from evaluation on the
+    # trace's XLA Modules line, and key the persistent compile cache
+    def train_step(state: TrainState, batch: GraphBatch):
         batch = guard_mod.poison_batch(rules, state.step, batch)
         if guard:
             ng = jnp.sum(batch.graph_mask).astype(jnp.float32)
@@ -163,7 +165,9 @@ def make_train_step(
             return state, tot, tasks, ng, ok, gnorm
         return new_state, tot, tasks
 
-    return jax.jit(step, donate_argnums=0) if donate else jax.jit(step)
+    if donate:
+        return jax.jit(train_step, donate_argnums=0)
+    return jax.jit(train_step)
 
 
 def make_eval_step(
@@ -181,11 +185,11 @@ def make_eval_step(
     )
 
     @jax.jit
-    def step(state: TrainState, batch: GraphBatch):
+    def eval_step(state: TrainState, batch: GraphBatch):
         b = cast_batch(batch, compute_dtype)
         return loss_fn(state.params, state.batch_stats, b)
 
-    return step
+    return eval_step
 
 
 def fold_step_metrics(acc, tots, tasks, gs):
@@ -270,7 +274,7 @@ def make_superstep_fn(
         loss_fn = make_loss_fn(model, cfg, compute_grad_energy)
         rules = guard_mod.nan_injections()
 
-        def superstep(state, acc, batches):
+        def train_superstep(state, acc, batches):
             def body(st, batch):
                 batch = guard_mod.poison_batch(rules, st.step, batch)
                 b = cast_batch(batch, compute_dtype)
@@ -304,8 +308,8 @@ def make_superstep_fn(
             return state, fold_step_metrics(acc, tots, tasks, gs)
 
         if donate:
-            return jax.jit(superstep, donate_argnums=(0, 1))
-        return jax.jit(superstep)
+            return jax.jit(train_superstep, donate_argnums=(0, 1))
+        return jax.jit(train_superstep)
 
     eval_loss_fn = make_eval_loss_fn(model, cfg, compute_grad_energy)
 
@@ -504,28 +508,39 @@ def _run_epoch(
     superstep_max_k = 0
     prev_dispatch_end = None
     first_fetch = step0 > 0  # resume: time the fast-forwarded fetch
+    # The loop's host work by name (docs/OBSERVABILITY.md "Profiler
+    # alignment"): tr.region drives the RegionTimer and, while a
+    # profiler capture is live, puts a span on its clock, so a gap of
+    # the device can be set against what this thread was doing. Off it
+    # is the shared no-op context. Names are built once, not per step.
+    r_feed, r_step, r_clock, r_guard, r_hook, r_fetch = (
+        f"{region}/{site}"
+        for site in (
+            "feed_wait", "step", "clock_record", "guard_observe",
+            "step_hook", "epoch_fetch",
+        )
+    )
     it = iter(loader)
     while True:
         if max_batches is not None and n_batches >= max_batches:
             break
-        tr.start(f"{region}/dataload")
-        t_fetch = (
-            time.perf_counter()
-            if (first_fetch or clock is not None)
-            else 0.0
-        )
-        batch = next(it, None)
-        t_fetched = time.perf_counter() if clock is not None else 0.0
-        if first_fetch:
-            # Resume fast-forward cost: the first delivery pays the
-            # plan replay (skip_to collates nothing; this is the
-            # whole observable price of the mid-epoch cursor).
-            tr.sample(
-                "checkpoint/resume_fastforward_ms",
-                1e3 * (time.perf_counter() - t_fetch),
+        with tr.region(r_feed):
+            t_fetch = (
+                time.perf_counter()
+                if (first_fetch or clock is not None)
+                else 0.0
             )
-            first_fetch = False
-        tr.stop(f"{region}/dataload")
+            batch = next(it, None)
+            t_fetched = time.perf_counter() if clock is not None else 0.0
+            if first_fetch:
+                # Resume fast-forward cost: the first delivery pays the
+                # plan replay (skip_to collates nothing; this is the
+                # whole observable price of the mid-epoch cursor).
+                tr.sample(
+                    "checkpoint/resume_fastforward_ms",
+                    1e3 * (time.perf_counter() - t_fetch),
+                )
+                first_fetch = False
         if batch is None:
             break
         is_macro = isinstance(batch, MacroBatch)
@@ -557,8 +572,8 @@ def _run_epoch(
             )
         else:
             step_ctx = tr.step_annotation(f"{region}_step", n_batches)
-        tr.start(f"{region}/step")
-        with step_ctx:
+        # the dispatch is on the trace as the step annotation already
+        with tr.region(r_step, annotate=False), step_ctx:
             if is_macro:
                 if superstep_fn is None:
                     raise RuntimeError(
@@ -599,7 +614,6 @@ def _run_epoch(
             if trace_sync:
                 # graftlint: disable-next-line=host-sync -- HYDRAGNN_TPU_TRACE_LEVEL>0 opt-in: per-step barrier so tracer times device work, at the documented cost of the dispatch overlap
                 jax.block_until_ready(loss)
-        tr.stop(f"{region}/step")
         tr.note_trace_step()
         prev_dispatch_end = time.perf_counter()
         tr.sample(f"{region}/steps_per_dispatch", float(k))
@@ -618,20 +632,21 @@ def _run_epoch(
                     cap_fn, cap_args = superstep_fn, (state, acc, batch.batch)
                 else:
                     cap_fn, cap_args = step_fn, (state, batch)
-            clock.record(
-                step=n_batches,
-                k=k,
-                batch=batch,
-                is_macro=is_macro,
-                t_fetch_start=t_fetch,
-                t_fetch_end=t_fetched,
-                t_dispatch_start=t_dispatch,
-                t_dispatch_end=prev_dispatch_end,
-                loss_ref=loss,
-                ng_ref=None if is_macro else ng,
-                capture_fn=cap_fn,
-                capture_args=cap_args,
-            )
+            with tr.region(r_clock):
+                clock.record(
+                    step=n_batches,
+                    k=k,
+                    batch=batch,
+                    is_macro=is_macro,
+                    t_fetch_start=t_fetch,
+                    t_fetch_end=t_fetched,
+                    t_dispatch_start=t_dispatch,
+                    t_dispatch_end=prev_dispatch_end,
+                    loss_ref=loss,
+                    ng_ref=None if is_macro else ng,
+                    capture_fn=cap_fn,
+                    capture_args=cap_args,
+                )
         if train:
             # Preemption-drill injection site (utils/faults.py; inert
             # with no plan armed). Kill thresholds are in OPTIMIZER
@@ -649,18 +664,27 @@ def _run_epoch(
                 # contribution, so the accumulation chain below is
                 # untouched — and bitwise the unguarded chain on a
                 # healthy run.
-                guard.observe(
-                    step=n_batches, k=k, ok_ref=okg[0], gnorm_ref=okg[1]
-                )
+                with tr.region(r_guard):
+                    guard.observe(
+                        step=n_batches, k=k, ok_ref=okg[0],
+                        gnorm_ref=okg[1],
+                    )
         if not is_macro:
             if loss_sum is None:
-                loss_sum, tasks_sum, n_graphs = loss * ng, tasks * ng, ng
+                # ``ng + 0.0`` (bitwise ng): a buffer of the
+                # accumulator's own. A later superstep dispatch DONATES
+                # the accumulator, and ``ng`` itself may still be held,
+                # as this step's deferred ref, by the step clock.
+                loss_sum, tasks_sum, n_graphs = (
+                    loss * ng, tasks * ng, ng + 0.0
+                )
             else:
                 loss_sum = loss_sum + loss * ng
                 tasks_sum = tasks_sum + tasks * ng
                 n_graphs = n_graphs + ng
         if step_hook is not None:
-            step_hook(state, n_batches, (loss_sum, tasks_sum, n_graphs))
+            with tr.region(r_hook):
+                step_hook(state, n_batches, (loss_sum, tasks_sum, n_graphs))
     # Input-pipeline telemetry: surface this epoch's starvation delta
     # in the tracer next to the step regions (the pipeline flushes its
     # own collate/H2D/queue-depth samples at iterator close; this adds
@@ -684,29 +708,28 @@ def _run_epoch(
     # (0 rows = superstep off / no full groups this epoch).
     if superstep_max_k:
         tr.sample(f"{region}/superstep_k", float(superstep_max_k))
-    if loss_sum is None:
+    with tr.region(r_fetch):
+        if loss_sum is not None:
+            # Single host sync per epoch.
+            # graftlint: disable-next-line=host-sync -- the ONE amortized metrics fetch this loop exists to provide (vs the reference's per-batch .item())
+            loss_sum, tasks_sum, n_graphs = jax.device_get(
+                (loss_sum, tasks_sum, n_graphs)
+            )
         if clock is not None:
+            # Resolve the deferred step refs + emit the epoch's rows —
+            # one batched fetch of already-materialized scalars (the
+            # metrics fetch above has just drained the queue).
             clock.finish()
         if guard is not None:
+            # Default-cadence guard resolution: the predicate refs
+            # resolve HERE, at the fetch point that already exists —
+            # zero added host syncs. May raise GuardRollback/GuardHalt
+            # (the policy ladder); the epoch's metrics are then
+            # discarded by the caller's retry, but the telemetry rows
+            # above already landed.
             guard.epoch_end()
+    if loss_sum is None:
         return state, 0.0, np.zeros(1)
-    # Single host sync per epoch.
-    # graftlint: disable-next-line=host-sync -- the ONE amortized metrics fetch this loop exists to provide (vs the reference's per-batch .item())
-    loss_sum, tasks_sum, n_graphs = jax.device_get(
-        (loss_sum, tasks_sum, n_graphs)
-    )
-    if clock is not None:
-        # Resolve the deferred step refs + emit the epoch's rows — one
-        # batched fetch of already-materialized scalars (the metrics
-        # fetch above has just drained the queue).
-        clock.finish()
-    if guard is not None:
-        # Default-cadence guard resolution: the predicate refs resolve
-        # HERE, at the fetch point that already exists — zero added
-        # host syncs. May raise GuardRollback/GuardHalt (the policy
-        # ladder); the epoch's metrics are then discarded by the
-        # caller's retry, but the telemetry rows above already landed.
-        guard.epoch_end()
     denom = max(float(n_graphs), 1.0)
     return state, float(loss_sum) / denom, np.asarray(tasks_sum) / denom
 
@@ -1133,18 +1156,21 @@ def train_validate_test(
     # Epoch-gated jax.profiler trace (reference Profile section,
     # train_validate_test.py:290-292) + optional TensorBoard scalars
     # (reference SummaryWriter, train_validate_test.py:371-378).
-    from hydragnn_tpu.utils.tracer import Profiler
+    from hydragnn_tpu.utils import tracer as tr
 
-    profiler = Profiler(config)
+    profiler = tr.Profiler(config)
     tb_writer = None
     log_name = config.get("_log_name")
     if log_name and jax.process_index() == 0:
-        try:
-            from torch.utils.tensorboard import SummaryWriter
+        # the import drags torch and tensorflow in (about 20 s of the
+        # v5e cells' set-up, PERF.md): a set-up phase of its own
+        with telemetry.setup_phase("writers"):
+            try:
+                from torch.utils.tensorboard import SummaryWriter
 
-            tb_writer = SummaryWriter(log_dir=f"logs/{log_name}/tb")
-        except Exception:
-            tb_writer = None
+                tb_writer = SummaryWriter(log_dir=f"logs/{log_name}/tb")
+            except Exception:
+                tb_writer = None
 
     # Plateau scheduler: reference hardcodes factor=0.5/patience=5/
     # min_lr=1e-5 (run_training.py:119-121); configurable here via the
@@ -1294,149 +1320,215 @@ def train_validate_test(
         next_epoch = epoch + 1
         t0 = time.time()
         profiler.on_epoch_start(epoch)
-        # Telemetry context: the epoch number drives the compile
-        # observer's retrace-leak phase; the lr rides the step rows.
-        # Guarded — the off path must not pay the get_learning_rate
-        # host fetch (or any work) for a stream that isn't there.
-        if telemetry.active():
-            telemetry.note_epoch(
-                epoch, lr=get_learning_rate(state.opt_state)
-            )
-        elif telemetry.observer() is not None:
-            telemetry.note_epoch(epoch)
-        train_loader.set_epoch(epoch)
-        if monitor is not None:
-            monitor.note_epoch(epoch)
-        acc0, step0 = None, 0
-        if epoch == resume_epoch and resume_step > 0:
-            # Fast-forward the feed to the cursor; the accumulator
-            # re-seeds from the manifest's bit-exact partial sums.
-            train_loader.skip_to(
-                resume_branch_cursor
-                if resume_branch_cursor
-                else resume_step
-            )
-            acc0, step0 = resume_acc, resume_step
-        # Guard policy ladder: a GuardRollback escalation restores the
-        # last-known-good checkpoint, backs the LR off, fast-forwards
-        # past the poisoned region, and retries the epoch; GuardHalt
-        # propagates (the run cannot safely continue, and the report
-        # says why). Guard-off runs never enter the except arm.
-        while True:
-            step_hook = None
-            if interval > 0 and mid_epoch_ok:
-                last_save = {"step": step0}
+        # The epoch's host work by name, on the profiler's clock while a
+        # capture is live (tr.region; docs/OBSERVABILITY.md "Profiler
+        # alignment"). The profiler's own start and stop stay outside
+        # the spans: a span is recorded only if it begins and ends
+        # inside the capture.
+        with tr.region("epoch/boundary"):
+            # Telemetry context: the epoch number drives the compile
+            # observer's retrace-leak phase; the lr rides the step rows.
+            # Guarded — the off path must not pay the get_learning_rate
+            # host fetch (or any work) for a stream that isn't there.
+            if telemetry.active():
+                telemetry.note_epoch(
+                    epoch, lr=get_learning_rate(state.opt_state)
+                )
+            elif telemetry.observer() is not None:
+                telemetry.note_epoch(epoch)
+            train_loader.set_epoch(epoch)
+            if monitor is not None:
+                monitor.note_epoch(epoch)
+            acc0, step0 = None, 0
+            if epoch == resume_epoch and resume_step > 0:
+                # Fast-forward the feed to the cursor; the accumulator
+                # re-seeds from the manifest's bit-exact partial sums.
+                train_loader.skip_to(
+                    resume_branch_cursor
+                    if resume_branch_cursor
+                    else resume_step
+                )
+                acc0, step0 = resume_acc, resume_step
+        with tr.region("epoch/train"):
+            # Guard policy ladder: a GuardRollback escalation restores the
+            # last-known-good checkpoint, backs the LR off, fast-forwards
+            # past the poisoned region, and retries the epoch; GuardHalt
+            # propagates (the run cannot safely continue, and the report
+            # says why). Guard-off runs never enter the except arm.
+            while True:
+                step_hook = None
+                if interval > 0 and mid_epoch_ok:
+                    last_save = {"step": step0}
 
-                def step_hook(
-                    st, steps_done, acc, _epoch=epoch, _last=last_save
-                ):
-                    if steps_done - _last["step"] < interval:
-                        return
-                    _last["step"] = steps_done
-                    writer.save(
-                        st,
-                        kind="auto",
-                        epoch=_epoch,
-                        step=steps_done,
-                        acc=acc,
-                        loop=_loop_state(),
-                        branch_steps=_branch_cursor(steps_done),
+                    def step_hook(
+                        st, steps_done, acc, _epoch=epoch, _last=last_save
+                    ):
+                        if steps_done - _last["step"] < interval:
+                            return
+                        _last["step"] = steps_done
+                        writer.save(
+                            st,
+                            kind="auto",
+                            epoch=_epoch,
+                            step=steps_done,
+                            acc=acc,
+                            loop=_loop_state(),
+                            branch_steps=_branch_cursor(steps_done),
+                        )
+
+                try:
+                    state, train_loss, train_tasks = _run_epoch(
+                        train_step, state, train_loader, train=True,
+                        superstep_fn=superstep_train, n_tasks=n_tasks,
+                        acc0=acc0, step0=step0, step_hook=step_hook,
+                        guard=monitor,
                     )
-
-            try:
-                state, train_loss, train_tasks = _run_epoch(
-                    train_step, state, train_loader, train=True,
-                    superstep_fn=superstep_train, n_tasks=n_tasks,
-                    acc0=acc0, step0=step0, step_hook=step_hook,
-                    guard=monitor,
-                )
-                break
-            except GuardRollback as rb:
-                state, acc0, step0 = _guard_rollback(
-                    rb, monitor, state, epoch, train_loader, writer,
-                    scheduler, verbosity,
-                )
+                    break
+                except GuardRollback as rb:
+                    state, acc0, step0 = _guard_rollback(
+                        rb, monitor, state, epoch, train_loader, writer,
+                        scheduler, verbosity,
+                    )
         # Throughput/scaling mode: skip val/test epochs entirely
         # (reference HYDRAGNN_VALTEST, train_validate_test.py:343).
         valtest = os.environ.get(
             "HYDRAGNN_TPU_VALTEST", "1"
         ).lower() not in ("0", "false", "no")
         if valtest:
-            _, val_loss, val_tasks = _run_epoch(
-                eval_step, state, val_loader, train=False,
-                superstep_fn=superstep_eval, n_tasks=n_tasks,
-            )
-            _, test_loss, test_tasks = _run_epoch(
-                eval_step, state, test_loader, train=False,
-                superstep_fn=superstep_eval, n_tasks=n_tasks,
-            )
+            with tr.region("epoch/validate"):
+                _, val_loss, val_tasks = _run_epoch(
+                    eval_step, state, val_loader, train=False,
+                    superstep_fn=superstep_eval, n_tasks=n_tasks,
+                )
+            with tr.region("epoch/test"):
+                _, test_loss, test_tasks = _run_epoch(
+                    eval_step, state, test_loader, train=False,
+                    superstep_fn=superstep_eval, n_tasks=n_tasks,
+                )
         else:
             val_loss, val_tasks = train_loss, train_tasks
             test_loss, test_tasks = train_loss, train_tasks
 
-        lr = get_learning_rate(state.opt_state)
-        new_lr = scheduler.step(val_loss, lr)
-        if new_lr != lr:
-            state = state.replace(
-                opt_state=set_learning_rate(state.opt_state, new_lr)
-            )
-
         profiler.on_epoch_end(epoch)
-        hist.train_loss.append(train_loss)
-        hist.val_loss.append(val_loss)
-        hist.test_loss.append(test_loss)
-        hist.train_tasks.append(train_tasks)
-        hist.val_tasks.append(val_tasks)
-        hist.test_tasks.append(test_tasks)
-        hist.lr.append(new_lr)
-        hist.epoch_seconds.append(time.time() - t0)
-        # Per-epoch rollup row: the EXACT floats appended to the
-        # history above (JSON's shortest-repr float round-trips
-        # bit-exactly), so graftboard's reconstructed loss curve
-        # compares bitwise against History.
-        if telemetry.active():
-            telemetry.emit(
-                {
-                    "t": "epoch",
-                    "epoch": epoch,
-                    "train_loss": train_loss,
-                    "val_loss": val_loss,
-                    "test_loss": test_loss,
-                    "train_tasks": (
-                        np.asarray(train_tasks).reshape(-1).tolist()
-                    ),
-                    "lr": new_lr,
-                    "seconds": hist.epoch_seconds[-1],
-                }
+        with tr.region("epoch/boundary"):
+            lr = get_learning_rate(state.opt_state)
+            new_lr = scheduler.step(val_loss, lr)
+            if new_lr != lr:
+                state = state.replace(
+                    opt_state=set_learning_rate(state.opt_state, new_lr)
+                )
+
+            hist.train_loss.append(train_loss)
+            hist.val_loss.append(val_loss)
+            hist.test_loss.append(test_loss)
+            hist.train_tasks.append(train_tasks)
+            hist.val_tasks.append(val_tasks)
+            hist.test_tasks.append(test_tasks)
+            hist.lr.append(new_lr)
+            hist.epoch_seconds.append(time.time() - t0)
+            if epoch == epoch_start:
+                # the first epoch compiles every shape: the last
+                # phase of the run's set-up
+                telemetry.setup_row("epoch_0", 1e3 * hist.epoch_seconds[-1])
+            # Per-epoch rollup row: the EXACT floats appended to the
+            # history above (JSON's shortest-repr float round-trips
+            # bit-exactly), so graftboard's reconstructed loss curve
+            # compares bitwise against History.
+            if telemetry.active():
+                telemetry.emit(
+                    {
+                        "t": "epoch",
+                        "epoch": epoch,
+                        "train_loss": train_loss,
+                        "val_loss": val_loss,
+                        "test_loss": test_loss,
+                        "train_tasks": (
+                            np.asarray(train_tasks).reshape(-1).tolist()
+                        ),
+                        "lr": new_lr,
+                        "seconds": hist.epoch_seconds[-1],
+                    }
+                )
+                # Live memory telemetry at the epoch boundary: device
+                # allocator stats + host RSS (a partial row on backends
+                # without allocator counters — never fabricated).
+                telemetry.emit_memory("epoch", epoch=epoch)
+            if tb_writer is not None:
+                tb_writer.add_scalar("loss/train", train_loss, epoch)
+                tb_writer.add_scalar("loss/val", val_loss, epoch)
+                tb_writer.add_scalar("loss/test", test_loss, epoch)
+                tb_writer.add_scalar("lr", new_lr, epoch)
+                for ti, tv in enumerate(np.asarray(train_tasks).reshape(-1)):
+                    tb_writer.add_scalar(f"task{ti}/train", float(tv), epoch)
+
+            print_distributed(
+                verbosity,
+                1,
+                f"Epoch {epoch:4d} | train {train_loss:.6f} "
+                f"| val {val_loss:.6f} "
+                f"| test {test_loss:.6f} | lr {new_lr:.2e} "
+                f"| {time.time() - t0:.2f}s",
             )
-            # Live memory telemetry at the epoch boundary: device
-            # allocator stats + host RSS (a partial row on backends
-            # without allocator counters — never fabricated).
-            telemetry.emit_memory("epoch", epoch=epoch)
-        if tb_writer is not None:
-            tb_writer.add_scalar("loss/train", train_loss, epoch)
-            tb_writer.add_scalar("loss/val", val_loss, epoch)
-            tb_writer.add_scalar("loss/test", test_loss, epoch)
-            tb_writer.add_scalar("lr", new_lr, epoch)
-            for ti, tv in enumerate(np.asarray(train_tasks).reshape(-1)):
-                tb_writer.add_scalar(f"task{ti}/train", float(tv), epoch)
 
-        print_distributed(
-            verbosity,
-            1,
-            f"Epoch {epoch:4d} | train {train_loss:.6f} | val {val_loss:.6f} "
-            f"| test {test_loss:.6f} | lr {new_lr:.2e} "
-            f"| {time.time() - t0:.2f}s",
-        )
+            improved = val_loss < best_val
+            if improved:
+                best_val = val_loss
+                bad_epochs = 0
+                if use_ckpt and epoch >= warmup:
+                    if writer is not None:
+                        # Cursor (epoch+1, 0): epoch is fully inside the
+                        # saved state; the artifact keeps the epoch label.
+                        writer.save(
+                            state,
+                            kind="epoch",
+                            epoch=epoch + 1,
+                            step=0,
+                            label_epoch=epoch,
+                            loop=_loop_state(),
+                            branch_steps=_branch_cursor(0),
+                        )
+                    elif checkpoint_cb is not None:
+                        checkpoint_cb(state, epoch, val_loss)
+            else:
+                bad_epochs += 1
+                if early_stop and bad_epochs >= patience:
+                    print_distributed(
+                        verbosity, 1, f"Early stopping at epoch {epoch}"
+                    )
+                    break
+            if writer is not None and interval > 0 and not (
+                improved and use_ckpt and epoch >= warmup
+            ):
+                # Epoch-boundary cursor refresh: a kill during the NEXT
+                # epoch's early batches must not lose this epoch's
+                # bookkeeping (scheduler/early-stop state moved above).
+                writer.save(
+                    state,
+                    kind="auto",
+                    epoch=epoch + 1,
+                    step=0,
+                    loop=_loop_state(),
+                    branch_steps=_branch_cursor(0),
+                )
 
-        improved = val_loss < best_val
-        if improved:
-            best_val = val_loss
-            bad_epochs = 0
-            if use_ckpt and epoch >= warmup:
-                if writer is not None:
-                    # Cursor (epoch+1, 0): epoch is fully inside the
-                    # saved state; the artifact keeps the epoch label.
+            # Walltime-aware stop (reference SLURM time-left probe,
+            # train_validate_test.py:430-437): checkpoint + stop before the
+            # scheduler kills the job.
+            from hydragnn_tpu.utils.runtime import check_remaining
+
+            if not check_remaining(
+                float(training.get("walltime_min_seconds_left", 300.0))
+            ):
+                print_distributed(
+                    verbosity,
+                    1,
+                    f"Stopping at epoch {epoch}: job walltime nearly "
+                    "exhausted",
+                )
+                # use_ckpt: "Checkpoint": false wrote nothing here pre-PR
+                # (checkpoint_cb was None) — keep that opt-out; the end-of-
+                # run save below still makes the stop restartable.
+                if writer is not None and use_ckpt:
                     writer.save(
                         state,
                         kind="epoch",
@@ -1448,57 +1540,7 @@ def train_validate_test(
                     )
                 elif checkpoint_cb is not None:
                     checkpoint_cb(state, epoch, val_loss)
-        else:
-            bad_epochs += 1
-            if early_stop and bad_epochs >= patience:
-                print_distributed(
-                    verbosity, 1, f"Early stopping at epoch {epoch}"
-                )
                 break
-        if writer is not None and interval > 0 and not (
-            improved and use_ckpt and epoch >= warmup
-        ):
-            # Epoch-boundary cursor refresh: a kill during the NEXT
-            # epoch's early batches must not lose this epoch's
-            # bookkeeping (scheduler/early-stop state moved above).
-            writer.save(
-                state,
-                kind="auto",
-                epoch=epoch + 1,
-                step=0,
-                loop=_loop_state(),
-                branch_steps=_branch_cursor(0),
-            )
-
-        # Walltime-aware stop (reference SLURM time-left probe,
-        # train_validate_test.py:430-437): checkpoint + stop before the
-        # scheduler kills the job.
-        from hydragnn_tpu.utils.runtime import check_remaining
-
-        if not check_remaining(
-            float(training.get("walltime_min_seconds_left", 300.0))
-        ):
-            print_distributed(
-                verbosity,
-                1,
-                f"Stopping at epoch {epoch}: job walltime nearly exhausted",
-            )
-            # use_ckpt: "Checkpoint": false wrote nothing here pre-PR
-            # (checkpoint_cb was None) — keep that opt-out; the end-of-
-            # run save below still makes the stop restartable.
-            if writer is not None and use_ckpt:
-                writer.save(
-                    state,
-                    kind="epoch",
-                    epoch=epoch + 1,
-                    step=0,
-                    label_epoch=epoch,
-                    loop=_loop_state(),
-                    branch_steps=_branch_cursor(0),
-                )
-            elif checkpoint_cb is not None:
-                checkpoint_cb(state, epoch, val_loss)
-            break
 
     # Post-training phase: compiles from here on (BN-recalibration
     # forwards, collect-outputs eval, export) are new executables by

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from hydragnn_tpu.data.graph import GraphBatch
 from hydragnn_tpu.models.spec import ModelConfig
+from hydragnn_tpu.utils import tracer as tr
 
 
 def masked_mean(err: jax.Array, mask: jax.Array) -> jax.Array:
@@ -54,6 +55,7 @@ def head_loss(
     return masked_mean(elementwise_loss(kind, pred, target), mask)
 
 
+@tr.scoped("loss")
 def multihead_loss(
     outputs: List[jax.Array], batch: GraphBatch, cfg: ModelConfig
 ) -> Tuple[jax.Array, jax.Array]:

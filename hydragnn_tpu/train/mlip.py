@@ -27,6 +27,7 @@ from hydragnn_tpu.data.graph import GraphBatch
 from hydragnn_tpu.models.spec import ModelConfig
 from hydragnn_tpu.ops import segment_sum
 from hydragnn_tpu.train.losses import head_loss
+from hydragnn_tpu.utils import tracer as tr
 
 
 def predict_graph_energy(model, variables, batch: GraphBatch, cfg: ModelConfig, *, train: bool = False):
@@ -80,11 +81,17 @@ def energy_and_forces(
         )
         return jnp.sum(ge), (ge, new_bn)
 
-    grad_pos, (graph_e, new_bn) = jax.grad(esum, has_aux=True)(batch.pos)
-    forces = -grad_pos * batch.node_mask.astype(grad_pos.dtype)[:, None]
+    # the inner grad of MLIP training: forward and position-gradient
+    # of the energy read as one block in a trace
+    with tr.scope("forces"):
+        grad_pos, (graph_e, new_bn) = jax.grad(esum, has_aux=True)(
+            batch.pos
+        )
+        forces = -grad_pos * batch.node_mask.astype(grad_pos.dtype)[:, None]
     return graph_e, forces, new_bn
 
 
+@tr.scoped("loss")
 def energy_force_loss_terms(
     graph_e: jax.Array, forces: jax.Array, batch: GraphBatch, cfg: ModelConfig
 ) -> Tuple[jax.Array, jax.Array]:

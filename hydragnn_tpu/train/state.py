@@ -15,6 +15,8 @@ import optax
 from flax import struct
 from flax.core import FrozenDict
 
+from hydragnn_tpu.utils import tracer as tr
+
 
 @struct.dataclass
 class TrainState:
@@ -24,8 +26,11 @@ class TrainState:
     batch_stats: Any
 
     def apply_gradients(self, grads, tx: optax.GradientTransformation):
-        updates, new_opt_state = tx.update(grads, self.opt_state, self.params)
-        new_params = optax.apply_updates(self.params, updates)
+        with tr.scope("optimizer"):
+            updates, new_opt_state = tx.update(
+                grads, self.opt_state, self.params
+            )
+            new_params = optax.apply_updates(self.params, updates)
         return self.replace(
             step=self.step + 1, params=new_params, opt_state=new_opt_state
         )

@@ -103,6 +103,7 @@ from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Tuple
 
 from hydragnn_tpu.utils import faults
+from hydragnn_tpu.utils import tracer as tr
 
 SCHEMA_VERSION = 1
 
@@ -138,6 +139,9 @@ __all__ = [
     "install_observer",
     "observer",
     "close_run",
+    "setup_row",
+    "setup_phase",
+    "SetupClock",
 ]
 
 
@@ -895,6 +899,72 @@ def configure(
     return stream
 
 
+# ----------------------------------------------------------------------
+# Set-up phases
+# ----------------------------------------------------------------------
+
+# ``setup`` rows whose phase ended before the stream was configured
+# (run_training builds loaders and model first); None = not holding.
+_SETUP_HELD: Optional[List[dict]] = None
+
+
+def setup_row(phase: str, ms: float, **kw) -> None:
+    """One ``{"t": "setup", "phase", "ms"}`` row: where the time before
+    the first steady epoch went. Held in memory while ``SetupClock`` is
+    waiting for the stream, emitted otherwise (a no-op with no stream)."""
+    row = {"t": "setup", "phase": phase, "ms": round(float(ms), 3), **kw}
+    if _SETUP_HELD is not None:
+        _SETUP_HELD.append(row)
+    else:
+        emit(row)
+
+
+@contextlib.contextmanager
+def setup_phase(name: str):
+    """A named phase of the run's set-up: a ``tr.region`` (so it shows in
+    the RegionTimer's CSV as ``setup/<name>`` and on a live profiler
+    capture) and a ``setup`` row with its wall time."""
+    t0 = time.perf_counter()
+    try:
+        with tr.region(f"setup/{name}"):
+            yield
+    finally:
+        setup_row(name, 1e3 * (time.perf_counter() - t0))
+
+
+class SetupClock:
+    """``run_training``'s set-up cut into phases at its own boundaries:
+    ``phase(name)`` ends the open phase and begins the next. Rows are
+    held until ``stream_ready()`` says the telemetry stream is configured
+    (or is not going to be)."""
+
+    def __init__(self) -> None:
+        global _SETUP_HELD
+        _SETUP_HELD = []
+        self._open = contextlib.ExitStack()  # the open phase, if any
+
+    def phase(self, name: str) -> None:
+        self._open.close()
+        self._open.enter_context(setup_phase(name))
+
+    def end_phase(self) -> None:
+        self._open.close()
+
+    def stream_ready(self) -> None:
+        """The stream is open, or stays off: write what was held."""
+        global _SETUP_HELD
+        held, _SETUP_HELD = _SETUP_HELD or [], None
+        for row in held:
+            emit(row)
+
+    def close(self) -> None:
+        """End of ``run_training``, sound or not: nothing stays open, and
+        rows no stream ever came for are dropped."""
+        global _SETUP_HELD
+        self.end_phase()
+        _SETUP_HELD = None
+
+
 def close_run(stream: Optional[TelemetryStream]) -> None:
     """Tear down what ``configure`` built — closes the observer (its
     summary row lands in the stream first), then the stream. Only
@@ -1494,6 +1564,7 @@ class CompileObserver:
         self.cache_misses = 0
         self.events: List[dict] = []
         self.post_warmup: List[dict] = []
+        self._setup_emitted = False
 
     # -- lifecycle -----------------------------------------------------
 
@@ -1510,6 +1581,7 @@ class CompileObserver:
         """Detach (a closed observer receives no further events — the
         no-cross-test-leakage contract) and emit the summary row."""
         global _OBSERVER
+        self._emit_setup_row()
         if self.stream is not None:
             self.stream.emit({"t": "compile_summary", **self.summary()})
         if _OBSERVER is self:
@@ -1517,6 +1589,27 @@ class CompileObserver:
 
     def set_phase(self, phase: int) -> None:
         self.phase = int(phase)
+        if phase < 0 or phase >= self.warmup_phase:
+            self._emit_setup_row()
+
+    def _emit_setup_row(self) -> None:
+        """The warm-up's compilations as one ``setup`` row, written once,
+        when the warm-up ends: ``ms`` is XLA compilation plus retrieval
+        from the persistent cache (jax's ``backend_compile`` event spans
+        both), beside the count and the cache's hits and misses."""
+        if self._setup_emitted or self.stream is None:
+            return
+        self._setup_emitted = True
+        self.stream.emit(
+            {
+                "t": "setup",
+                "phase": "compile",
+                "ms": round(self.compile_ms, 3),
+                "compile_count": self.compile_count,
+                "cache_hits": self.cache_hits,
+                "cache_misses": self.cache_misses,
+            }
+        )
 
     # -- event sinks (called from the module dispatchers) --------------
 

@@ -1,4 +1,4 @@
-"""Region tracing + profiling.
+"""Region tracing + profiling: one vocabulary of scopes and spans.
 
 TPU-native equivalent of the reference's tracer multiplexer
 (hydragnn/utils/profiling_and_tracing/tracer.py:361-483: registry of
@@ -6,18 +6,25 @@ optional tracers, ``tr.start/stop`` with optional device sync,
 ``@tr.profile`` decorator, CSV dumps) and of the epoch-gated
 torch.profiler wrapper (profiling_and_tracing/profile.py:9-70).
 
-Tracers here:
-- ``RegionTimer`` — hierarchical wall-clock regions with call counts
-  (GPTL-equivalent), per-process CSV dump.
-- ``JaxProfilerTracer`` — wraps ``jax.profiler`` trace capture; the
-  resulting TensorBoard trace includes XLA device timelines (the
-  TPU-native replacement for NVML/ROCm counters: device activity comes
-  from the runtime, not a sideband poller).
-- ``DeviceMetricsTracer`` — per-region device counters (HBM bytes in
-  use/peak via libtpu's ``memory_stats``, duty cycle via ``tpu-info``
-  when installed); the analog of the reference's NVML/ROCm energy
-  pollers (tracer.py:114-358). Inert on backends with no counters
-  (CPU), so it is always safe to install.
+Three helpers name the work where it happens (docs/OBSERVABILITY.md
+"Profiler alignment" lists every name):
+
+- ``scope(name)`` -- a ``jax.named_scope`` from the fixed ``SCOPES``
+  vocabulary around DEVICE work. Compile-time metadata only: the name
+  lands in every enclosed op's HLO ``op_name`` (the trace's ``tf_op``),
+  so a trace is read by layer and phase whatever implements the op.
+  Forward and backward need no scope: JAX writes ``jvp(`` /
+  ``transpose(`` into the same path.
+- ``span(name)`` -- a ``jax.profiler.TraceAnnotation`` around HOST work
+  on any thread, while a capture started here is live; the shared no-op
+  context otherwise.
+- ``region(name)`` -- a ``span`` that also drives the installed tracers
+  (``RegionTimer`` under ``HYDRAGNN_TPU_TRACE_LEVEL``, dumped to
+  ``logs/<run>/timing.p<rank>.csv``). Loop thread only: the timer nests
+  regions on one stack.
+
+Device activity comes from the profiler's trace (``Profiler``), device
+memory from ``memory_stats()``; no sideband poller lives here.
 
 Device sync: JAX dispatch is async; ``sync=True`` inserts a
 ``block_until_ready`` barrier so region times measure device completion
@@ -26,6 +33,7 @@ Device sync: JAX dispatch is async; ``sync=True`` inserts a
 
 from __future__ import annotations
 
+import contextlib
 import csv
 import functools
 import os
@@ -44,11 +52,15 @@ __all__ = [
     "save",
     "has",
     "Profiler",
-    "DeviceMetricsTracer",
     "jax_trace_active",
     "set_trace_step_budget",
     "note_trace_step",
     "step_annotation",
+    "SCOPES",
+    "scope",
+    "scoped",
+    "span",
+    "region",
 ]
 
 _TRACERS: Dict[str, Any] = {}
@@ -118,239 +130,48 @@ class RegionTimer:
         self.__init__()
         self.enabled = enabled
 
-    def save_csv(
-        self, path: str, device_columns: Optional[Dict[str, Dict]] = None
-    ) -> None:
-        """``device_columns``: {region_key -> {column -> value}} merged
-        in per row (the DeviceMetricsTracer's per-region counters), so
-        one CSV carries wall-clock AND device columns on TPU."""
+    def save_csv(self, path: str) -> None:
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        extra_names: List[str] = []
-        if device_columns:
-            seen = set()
-            for cols in device_columns.values():
-                for name in cols:
-                    if name not in seen:
-                        seen.add(name)
-                        extra_names.append(name)
         with open(path, "w", newline="") as f:
             w = csv.writer(f)
             w.writerow(
                 ["region", "count", "total_s", "min_s", "max_s", "avg_s"]
-                + extra_names
             )
             for k in sorted(self.totals):
                 c = self.counts[k]
-                row = [
-                    k,
-                    c,
-                    f"{self.totals[k]:.6f}",
-                    f"{self.mins[k]:.6f}",
-                    f"{self.maxs[k]:.6f}",
-                    f"{self.totals[k] / max(c, 1):.6f}",
-                ]
-                cols = (device_columns or {}).get(k, {})
-                row += [cols.get(name, "") for name in extra_names]
-                w.writerow(row)
+                w.writerow(
+                    [
+                        k,
+                        c,
+                        f"{self.totals[k]:.6f}",
+                        f"{self.mins[k]:.6f}",
+                        f"{self.maxs[k]:.6f}",
+                        f"{self.totals[k] / max(c, 1):.6f}",
+                    ]
+                )
 
 
-def _default_device_counters() -> Optional[Dict[str, float]]:
-    """Read the local device's runtime counters.
-
-    On TPU, ``Device.memory_stats()`` surfaces libtpu's allocator
-    telemetry (bytes_in_use, peak_bytes_in_use, ...); if a ``tpu-info``
-    CLI is on PATH its duty-cycle sample is folded in. Returns None
-    when the backend publishes nothing (CPU) — the tracer then stays
-    inert, matching the reference pollers that no-op without
-    NVML/ROCm-SMI (tracer.py:114-358)."""
-    try:
-        import jax
-
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
-        return None
-    if not stats:
-        return None
-    out = {
-        "hbm_bytes_in_use": float(stats.get("bytes_in_use", 0)),
-        "hbm_peak_bytes": float(stats.get("peak_bytes_in_use", 0)),
-    }
-    duty = _read_tpu_duty_cycle()
-    if duty is not None:
-        out["duty_cycle_pct"] = duty
-    return out
-
-
-_DUTY_CACHE = {"exe": False, "t": 0.0, "value": None}
-_DUTY_MIN_INTERVAL_S = 5.0
-
-
-def _read_tpu_duty_cycle() -> Optional[float]:
-    """Duty-cycle sample via the ``tpu-info`` CLI (libtpu SDK metrics),
-    when installed; None otherwise. Region boundaries fire 4x per
-    training batch, so the subprocess is rate-limited: at most one
-    spawn per _DUTY_MIN_INTERVAL_S, the cached value in between (a duty
-    cycle is itself a windowed average — stale-by-seconds is fine)."""
-    import shutil
-    import subprocess
-
-    if _DUTY_CACHE["exe"] is False:  # resolve PATH once
-        _DUTY_CACHE["exe"] = shutil.which("tpu-info")
-    exe = _DUTY_CACHE["exe"]
-    if exe is None:
-        return None
-    now = time.monotonic()
-    if now - _DUTY_CACHE["t"] < _DUTY_MIN_INTERVAL_S:
-        return _DUTY_CACHE["value"]
-    _DUTY_CACHE["t"] = now
-    value = None
-    try:
-        # Preferred: a --metric flag (present on some tpu-info builds);
-        # fall back to parsing the default table for a duty-cycle row.
-        # A nonzero exit (unknown flag, no TPU) must never let an error
-        # banner's first number masquerade as a duty cycle.
-        proc = subprocess.run(
-            [exe, "--metric", "duty_cycle_pct"],
-            capture_output=True,
-            text=True,
-            timeout=2,
-        )
-        if proc.returncode == 0:
-            value = _first_percentage(proc.stdout.splitlines())
-        if value is None:
-            proc = subprocess.run(
-                [exe], capture_output=True, text=True, timeout=2
-            )
-            # Only trust the table when it actually reports a duty
-            # cycle (the value rows don't repeat the header word, so
-            # gate on the whole output and let the %-preference in
-            # _first_percentage skip chip indexes / ordinals).
-            if proc.returncode == 0 and "duty" in proc.stdout.lower():
-                value = _first_percentage(proc.stdout.splitlines())
-    except Exception:
-        value = None
-    _DUTY_CACHE["value"] = value
-    return value
-
-
-def _first_percentage(lines) -> Optional[float]:
-    """First percentage token in [0, 100]. '%'-suffixed tokens win over
-    bare numbers (a table row may lead with a chip index), and values
-    outside [0, 100] are rejected — an ordinal or error-banner number
-    can never be logged as a duty cycle."""
-    fallback = None
-    for ln in lines:
-        for tok in ln.split():
-            try:
-                v = float(tok.rstrip("%"))
-            except ValueError:
-                continue
-            if not (0.0 <= v <= 100.0):
-                continue
-            if tok.endswith("%"):
-                return v
-            if fallback is None:
-                fallback = v
-    return fallback
-
-
-class DeviceMetricsTracer:
-    """Per-region device counters sampled at region start/stop — the
-    TPU-side analog of the reference's NVML / ROCm-SMI energy tracers
-    (hydragnn/utils/profiling_and_tracing/tracer.py:114-358), reading
-    the JAX runtime's own telemetry instead of a sideband SMI tool.
-
-    Per region it accumulates, for each counter the reader exposes:
-    ``<name>_delta`` (sum of stop-start over calls — e.g. bytes
-    allocated inside the region) and ``<name>_max`` (max value seen at
-    a boundary). ``read_fn`` is injectable for tests and for richer
-    pollers (a libtpu metrics service, an external power meter)."""
-
-    def __init__(self, read_fn: Optional[Callable] = None) -> None:
-        self._read = read_fn or _default_device_counters
-        self.active = self._read() is not None
-        self.enabled = True
-        self._open: Dict[str, Dict[str, float]] = {}
-        self._stack: List[str] = []
-        self.deltas: Dict[str, Dict[str, float]] = {}
-        self.maxes: Dict[str, Dict[str, float]] = {}
-
-    def start(self, name: str) -> None:
-        if not (self.enabled and self.active):
-            return
-        self._stack.append(name)
-        snap = self._read()
-        if snap is not None:
-            self._open[self._key()] = snap
-
-    def stop(self, name: str) -> None:
-        if not (self.enabled and self.active):
-            return
-        if name not in self._stack:
-            # Stop without a start: ignore, keeping the stack AND the
-            # enclosing region's open snapshot intact (any open entry
-            # under the current key belongs to a region still on the
-            # stack — mirrors RegionTimer's tolerance for unbalanced
-            # regions; one bad call must not erase a live region).
-            return
-        # Truncate to the matching start, discarding orphaned opens of
-        # regions that were started but never stopped above it.
-        while self._stack[-1] != name:
-            self._open.pop(self._key(), None)
-            self._stack.pop()
-        key = self._key()
-        self._stack.pop()
-        before = self._open.pop(key, None)
-        after = self._read()
-        if before is None or after is None:
-            return
-        d = self.deltas.setdefault(key, {})
-        m = self.maxes.setdefault(key, {})
-        for cname, val in after.items():
-            d[cname] = d.get(cname, 0.0) + (val - before.get(cname, val))
-            m[cname] = max(m.get(cname, val), val, before.get(cname, val))
-
-    def _key(self) -> str:
-        return "/".join(self._stack)
-
-    def enable(self) -> None:
-        self.enabled = True
-
-    def disable(self) -> None:
-        self.enabled = False
-
-    def reset(self) -> None:
-        self._open.clear()
-        self._stack.clear()
-        self.deltas.clear()
-        self.maxes.clear()
-
-    def columns(self) -> Dict[str, Dict[str, float]]:
-        """{region -> {csv column -> value}} for RegionTimer.save_csv."""
-        out: Dict[str, Dict[str, float]] = {}
-        for key in set(self.deltas) | set(self.maxes):
-            cols: Dict[str, float] = {}
-            for cname, val in self.deltas.get(key, {}).items():
-                cols[f"{cname}_delta"] = val
-            for cname, val in self.maxes.get(key, {}).items():
-                cols[f"{cname}_max"] = val
-            out[key] = cols
-        return out
-
-
-_JAX_TRACE_ACTIVE = False  # one jax.profiler trace at a time (shared
-# between JaxProfilerTracer and the epoch-gated Profiler below)
+_JAX_TRACE_ACTIVE = False  # one jax.profiler trace at a time
 _TRACE_STEP_BUDGET: Optional[int] = None  # dispatches left in window
-_NULL_CTX = None  # shared reusable no-op context (built lazily)
+_NULL_CTX = contextlib.nullcontext()  # the shared reusable no-op context
 
 
-def _start_jax_trace(trace_dir: str) -> bool:
+def _start_jax_trace(trace_dir: str, python_tracer: bool = False) -> bool:
+    """THE one place the package starts a jax.profiler capture. The
+    Python tracer is off unless asked for (``Training.Profiling.
+    python_tracer``): jax's default, on, traces every Python call and
+    stretched a 16.7 s epoch of this host-heavy loop to 27.5 s on the
+    v5e (PERF.md, PR 26). The host tracer stays at 2, which keeps the
+    TraceMe spans ``span``/``region``/``step_annotation`` write."""
     global _JAX_TRACE_ACTIVE
     if _JAX_TRACE_ACTIVE:
         return False
     import jax
 
-    jax.profiler.start_trace(trace_dir)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 1 if python_tracer else 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
     _JAX_TRACE_ACTIVE = True
     return True
 
@@ -366,10 +187,10 @@ def _stop_jax_trace() -> None:
 
 
 def jax_trace_active() -> bool:
-    """True while a jax.profiler capture started HERE (Profiler /
-    JaxProfilerTracer) is live — the epoch loop's cheap per-step gate
-    for StepTraceAnnotation metadata: profiling off costs one module-
-    global read per dispatch, nothing else."""
+    """True while a jax.profiler capture started HERE (Profiler) is
+    live — the epoch loop's cheap per-step gate for StepTraceAnnotation
+    metadata: profiling off costs one module-global read per dispatch,
+    nothing else."""
     return _JAX_TRACE_ACTIVE
 
 
@@ -400,18 +221,102 @@ def step_annotation(region: str, step: int, **meta):
     context — so per-dispatch trace annotation costs nothing when
     profiling is off, and the captured timeline aligns device ops to
     the loop's own step numbering when it is on."""
-    global _NULL_CTX
     if not _JAX_TRACE_ACTIVE:
-        if _NULL_CTX is None:
-            import contextlib
-
-            _NULL_CTX = contextlib.nullcontext()
         return _NULL_CTX
     import jax
 
     return jax.profiler.StepTraceAnnotation(
         region, step_num=int(step), **meta
     )
+
+
+# The vocabulary of device scopes (docs/OBSERVABILITY.md "Profiler
+# alignment"; read by benchmarks/scopes.py). ``segment`` carries the
+# primitive after a slash: ``segment/sum``, ``segment/softmax``, ...
+SCOPES = (
+    "edge_geometry",
+    "edge_aggregate",
+    "segment",
+    "pool",
+    "loss",
+    "forces",
+    "optimizer",
+    "guard",
+)
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of ``SCOPES``: the ops
+    traced inside carry ``.../<name>/...`` in their HLO ``op_name``.
+    Metadata only — nothing runs on the device or per step for it. A
+    model that opens ``edge_aggregate`` around a helper that opens it
+    too reads ``edge_aggregate/edge_aggregate``; readers fold the
+    repeat (benchmarks/scopes.py)."""
+    if name.split("/", 1)[0] not in SCOPES:
+        raise ValueError(f"scope {name!r} is not in tracer.SCOPES")
+    import jax
+
+    return jax.named_scope(name)
+
+
+def scoped(name: str) -> Callable:
+    """Decorator: trace the function's body under ``scope(name)``."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(*a, **kw):
+            with scope(name):
+                return fn(*a, **kw)
+
+        return wrapped
+
+    return deco
+
+
+def span(name: str):
+    """A host span on the profiler's clock: ``TraceAnnotation(name)``
+    while a capture started here is live, else the shared no-op
+    context (one module-global read). Safe on any thread. No span's
+    name may end in ``_step``: that suffix marks the loop's thread
+    (``step_annotation``) for the trace's readers."""
+    if not _JAX_TRACE_ACTIVE:
+        return _NULL_CTX
+    import jax
+
+    return jax.profiler.TraceAnnotation(name)
+
+
+class _Region:
+    """An entered ``region``: tracers outside, the profiler span inside."""
+
+    __slots__ = ("name", "ann")
+
+    def __init__(self, name: str, ann) -> None:
+        self.name = name
+        self.ann = ann
+
+    def __enter__(self) -> None:
+        start(self.name)
+        self.ann.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        self.ann.__exit__(*exc)
+        stop(self.name)
+        return False
+
+
+def region(name: str, annotate: bool = True):
+    """One context manager per named site of host work: drives the
+    installed tracers as a ``start``/``stop`` pair does and, with
+    ``annotate``, is a ``span`` as well. Off — no tracer installed, no
+    live capture — it is the shared no-op context, with no allocation.
+    The loop's thread only (``RegionTimer`` keeps one stack); other
+    threads take ``span``. ``annotate=False`` is for the dispatch
+    site, which ``step_annotation`` already puts on the trace."""
+    ann = span(name) if annotate else _NULL_CTX
+    if not _TRACERS:
+        return ann
+    return _Region(name, ann)
 
 
 def _emit_profile_row(event: str, **kw) -> None:
@@ -427,39 +332,6 @@ def _emit_profile_row(event: str, **kw) -> None:
         pass
 
 
-class JaxProfilerTracer:
-    """Capture ONE jax.profiler trace around the region named
-    ``region`` (default "trace") while enabled. Per-batch loop regions
-    (train/step etc.) do not match, so enabling this tracer does not
-    flush a trace per batch."""
-
-    def __init__(
-        self, trace_dir: str = "logs/jax_trace", region: str = "trace"
-    ) -> None:
-        self.trace_dir = trace_dir
-        self.region = region
-        self.enabled = False
-        self._owner = False
-
-    def start(self, name: str) -> None:
-        if self.enabled and name == self.region:
-            self._owner = _start_jax_trace(self.trace_dir)
-
-    def stop(self, name: str) -> None:
-        if self.enabled and name == self.region and self._owner:
-            _stop_jax_trace()
-            self._owner = False
-
-    def enable(self) -> None:
-        self.enabled = True
-
-    def disable(self) -> None:
-        self.enabled = False
-
-    def reset(self) -> None:
-        self._owner = False
-
-
 def initialize(
     trlist: Optional[List[str]] = None, verbose: bool = False, **kwargs
 ) -> None:
@@ -467,11 +339,7 @@ def initialize(
     forwarded only to the tracers whose constructors accept them."""
     import inspect
 
-    classes = {
-        "RegionTimer": RegionTimer,
-        "JaxProfilerTracer": JaxProfilerTracer,
-        "DeviceMetricsTracer": DeviceMetricsTracer,
-    }
+    classes = {"RegionTimer": RegionTimer}
     for name in trlist or ["RegionTimer"]:
         cls = classes[name]
         accepted = set(inspect.signature(cls.__init__).parameters)
@@ -557,13 +425,8 @@ def save(log_name: str) -> None:
 
     rank = jax.process_index() if jax.process_count() > 1 else 0
     if has("RegionTimer"):
-        device_columns = None
-        dm = _TRACERS.get("DeviceMetricsTracer")
-        if dm is not None and dm.active:
-            device_columns = dm.columns()
         _TRACERS["RegionTimer"].save_csv(
-            os.path.join("logs", log_name, f"timing.p{rank}.csv"),
-            device_columns=device_columns,
+            os.path.join("logs", log_name, f"timing.p{rank}.csv")
         )
 
 
@@ -573,13 +436,15 @@ class Profiler:
     with enable + target epoch; traces land in a TensorBoard dir).
 
     Preferred config is the ``Training.Profiling {enabled, epoch,
-    steps, trace_dir}`` block (docs/OBSERVABILITY.md "Profiler
-    alignment"): capture epoch ``epoch``, optionally bounded to the
-    first ``steps`` dispatches (a steady-state window small enough to
-    open in TensorBoard; 0 = whole epoch). While the capture is live
-    the epoch loop wraps every dispatch in a ``StepTraceAnnotation``
-    carrying step/spec/k metadata (``step_annotation``), and the
-    window's start/stop land in the telemetry stream as ``profile``
+    steps, trace_dir, python_tracer}`` block (docs/OBSERVABILITY.md
+    "Profiler alignment"): capture epoch ``epoch``, optionally bounded
+    to the first ``steps`` dispatches (a steady-state window small
+    enough to open in TensorBoard; 0 = whole epoch), with the Python
+    tracer off unless ``python_tracer`` is true. While the capture is
+    live the epoch loop wraps every dispatch in a
+    ``StepTraceAnnotation`` carrying step/spec/k metadata
+    (``step_annotation``) and its host work in ``region`` spans, and
+    the window's start/stop land in the telemetry stream as ``profile``
     rows so graftboard reports can point at the trace. The legacy
     top-level ``Profile {enable, target_epoch, trace_dir}`` section
     keeps working unchanged."""
@@ -596,17 +461,21 @@ class Profiler:
             self.target_epoch = int(pcfg.get("epoch", 0))
             self.steps = max(0, int(pcfg.get("steps", 0)))
             self.trace_dir = pcfg.get("trace_dir", "logs/jax_trace")
+            self.python_tracer = bool(pcfg.get("python_tracer", False))
         else:
             cfg = config.get("Profile", {})
             self.enabled = bool(cfg.get("enable", 0))
             self.target_epoch = int(cfg.get("target_epoch", 0))
             self.steps = 0
             self.trace_dir = cfg.get("trace_dir", "logs/jax_trace")
+            self.python_tracer = False
         self._active = False
 
     def on_epoch_start(self, epoch: int) -> None:
         if self.enabled and epoch == self.target_epoch:
-            self._active = _start_jax_trace(self.trace_dir)
+            self._active = _start_jax_trace(
+                self.trace_dir, self.python_tracer
+            )
             if self._active:
                 set_trace_step_budget(self.steps or None)
                 _emit_profile_row(

@@ -1211,6 +1211,11 @@ def test_host_sync_roofline_capture_paths_are_covered():
         "memory_row",
         "note_trace_step",
         "step_annotation",
+        "region",
+        "span",
+        "scope",
+        "_Region.__enter__",
+        "_Region.__exit__",
     ):
         assert any(
             graph.find(p, q) for p, q in HOT_SEEDS if q == qual
@@ -1234,6 +1239,17 @@ def test_host_sync_roofline_capture_paths_are_covered():
     )
     f = findings_of({"hydragnn_tpu/utils/tracer.py": bad_tr}, [HostSyncRule()])
     assert any("device_get" in x.message for x in f), [x.message for x in f]
+    # and into a region's entry (ISSUE 27: it opens around every site
+    # of the loop's host work)
+    bad_region = (
+        "class _Region:\n"
+        "    def __enter__(self):\n"
+        "        self.loss.block_until_ready()\n"
+    )
+    f = findings_of(
+        {"hydragnn_tpu/utils/tracer.py": bad_region}, [HostSyncRule()]
+    )
+    assert any("block_until_ready" in x.message for x in f), f
     # the real tracer file is clean under the rule (the telemetry
     # file's cleanliness is pinned by the ISSUE-7 test above)
     src = next(
@@ -2060,7 +2076,7 @@ def test_hot_coverage_ratchet_catches_hot_seed_removal():
         res = run_lint(REPO, rules=[HotCoverageRule()],
                        baseline_path=None)
         assert any(
-            "make_train_step.step" in x.message for x in res.findings
+            "make_train_step.train_step" in x.message for x in res.findings
         ), [x.render() for x in res.findings]
     finally:
         host_sync.HOT_SEEDS = kept

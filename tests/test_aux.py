@@ -175,3 +175,41 @@ def test_lsms_gibbs_conversion(tmp_path):
     # pure configs have zero formation enthalpy
     g0 = float(open(os.path.join(out, "pure0.txt")).readline().split()[0])
     np.testing.assert_allclose(g0, 0.0, atol=1e-10)
+
+
+def test_per_test_limit_fails_the_test_by_name(tmp_path):
+    """tests/conftest.py's limit, cut to 1 s in a pytest session of its
+    own: a test that sleeps past it fails under its own name with every
+    thread's stack on stderr, and the session goes on to the next test
+    — it is not the outer timeout that ends it."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    (tmp_path / "conftest.py").write_text(
+        "import tests.conftest\n"
+        "tests.conftest.TEST_LIMIT_S = 1\n"
+        "from tests.conftest import (  # noqa: F401\n"
+        "    pytest_configure, pytest_runtest_call, pytest_runtest_setup,\n"
+        ")\n"
+    )
+    (tmp_path / "test_nap.py").write_text(
+        "import time\n\n\n"
+        "def test_sleeps_past_the_limit():\n    time.sleep(600)\n\n\n"
+        "def test_after_it():\n    pass\n"
+    )
+    r = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:xdist", str(tmp_path)],
+        capture_output=True, text=True, cwd=tmp_path, timeout=240,
+        env=dict(os.environ, PYTHONPATH=repo),
+    )
+    assert r.returncode == 1, r.stdout + r.stderr
+    assert "1 failed, 1 passed" in r.stdout, r.stdout
+    assert (
+        "test_nap.py::test_sleeps_past_the_limit ran past the 1 s limit"
+        in r.stdout
+    ), r.stdout
+    # faulthandler's dump, taken while the test slept
+    assert "Timeout (0:00:01)!" in r.stderr, r.stderr
+    assert "in test_sleeps_past_the_limit" in r.stderr, r.stderr

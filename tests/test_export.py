@@ -257,14 +257,14 @@ json.dump(config, open("cfg.json", "w"))
     r = subprocess.run(
         [sys.executable, "-c", script],
         cwd=tmp_path, env=env, capture_output=True, text=True,
-        timeout=420,
+        timeout=240,
     )
     assert r.returncode == 0, r.stderr[-2000:]
     r = subprocess.run(
         [sys.executable, "-m", "hydragnn_tpu.export", "cfg.json",
          "model.hlo"],
         cwd=tmp_path, env=env, capture_output=True, text=True,
-        timeout=420,
+        timeout=240,
     )
     assert r.returncode == 0, r.stderr[-2000:]
     info = json.loads(r.stdout.strip().splitlines()[-1])

@@ -15,6 +15,7 @@ import os
 import socket
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -44,7 +45,7 @@ def _free_port():
 def test_two_process_training(tmp_path, parallelism):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     port = _free_port()
-    procs = []
+    procs, logs = [], []
     for pid in range(2):
         env = dict(os.environ)
         env.update(
@@ -59,39 +60,57 @@ def test_two_process_training(tmp_path, parallelism):
             }
         )
         # The pytest session's XLA_FLAGS pin 8 host devices; the workers
-        # use jax_num_cpu_devices=4 instead.
+        # use jax_num_cpu_devices=4 instead. And the suite's other
+        # workers already hold the host's cores: one compute thread a
+        # device, not 2 processes x 4 devices x a pool as wide as the
+        # host.
         env["XLA_FLAGS"] = " ".join(
-            f
-            for f in env.get("XLA_FLAGS", "").split()
-            if "xla_force_host_platform_device_count" not in f
+            [
+                f
+                for f in env.get("XLA_FLAGS", "").split()
+                if "xla_force_host_platform_device_count" not in f
+            ]
+            + ["--xla_cpu_multi_thread_eigen=false"]
         )
-        procs.append(
-            subprocess.Popen(
-                [
-                    sys.executable,
-                    os.path.join(repo, "tests", "multihost_worker.py"),
-                    str(tmp_path),
-                ],
-                env=env,
-                cwd=repo,
-                stdout=subprocess.PIPE,
-                stderr=subprocess.STDOUT,
-                text=True,
+        env.update(
+            OMP_NUM_THREADS="1", OPENBLAS_NUM_THREADS="1", MKL_NUM_THREADS="1"
+        )
+        logs.append(tmp_path / f"worker_{pid}.log")
+        with open(logs[-1], "w") as log:
+            procs.append(
+                subprocess.Popen(
+                    [
+                        sys.executable,
+                        os.path.join(repo, "tests", "multihost_worker.py"),
+                        str(tmp_path),
+                    ],
+                    env=env,
+                    cwd=repo,
+                    stdout=log,
+                    stderr=subprocess.STDOUT,
+                )
             )
-        )
-    outs = []
+    # ~30 s uncontended, under three minutes on a host with twice as
+    # many busy processes as cores: one limit for both workers, below
+    # the per-test one of tests/conftest.py. A worker that dies leaves
+    # its peer waiting at the rendezvous, so the first failure ends the
+    # wait; either way both workers' output names what happened (under
+    # load: gloo's own 30 s limit on the slower worker's arrival at the
+    # first cross-process collective, "Gloo context initialization
+    # failed: DEADLINE_EXCEEDED").
+    deadline = time.monotonic() + 300
+    while time.monotonic() < deadline:
+        codes = [p.poll() for p in procs]
+        if any(codes) or None not in codes:
+            break
+        time.sleep(0.2)
     for p in procs:
-        try:
-            # generous: ~50s uncontended, but the 2 coordinated workers
-            # stall hard when the host is oversubscribed
-            out, _ = p.communicate(timeout=1200)
-        except subprocess.TimeoutExpired:
-            for q in procs:
-                q.kill()
-            raise
-        outs.append(out)
-    for p, out in zip(procs, outs):
-        assert p.returncode == 0, f"worker failed:\n{out[-4000:]}"
+        p.kill()
+    assert [p.wait() for p in procs] == [0, 0], "".join(
+        f"--- worker {pid}: exit {p.returncode} ---\n"
+        f"{log.read_text()[-4000:]}\n"
+        for pid, (p, log) in enumerate(zip(procs, logs))
+    )
 
     hists = []
     for pid in range(2):

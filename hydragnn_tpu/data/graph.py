@@ -87,6 +87,13 @@ class GraphBatch:
     seg_valid: Optional[jax.Array] = None  # [B*be] bool
     seg_window: Optional[jax.Array] = None  # [B] int32
 
+    # Collation's promise that ``receivers`` is nondecreasing (padding
+    # edges included: they target the first padding node). Static, so a
+    # jitted step reads it at trace time: the receiver aggregation then
+    # tells XLA its scatter's indices are sorted (ops/segment.py).
+    # Whoever writes other receivers into a batch must drop it.
+    receivers_sorted: bool = struct.field(pytree_node=False, default=False)
+
     # ------------------------------------------------------------------
     @property
     def num_nodes(self) -> int:
@@ -401,31 +408,69 @@ def count_triplets(sample: "GraphSample") -> int:
     return total - reciprocal
 
 
-def apply_segment_plan(senders, receivers, edge_mask, edge_payloads, e_real, N):
-    """Sort REAL edges by receiver IN PLACE (padding edges already
-    target the first padding node, which sorts after every real
-    receiver) and build the static-size block plan for the Pallas
-    aggregation kernel. The ONE implementation shared by ``collate``
-    and the packed collators (data/pipeline.py), whose contract is
-    bit-identity with it. ``N`` is the padded node count; returns
-    (seg_perm, seg_ids, seg_valid, seg_window)."""
-    from hydragnn_tpu.ops.pallas_segment import (
-        plan_blocks_static,
-        static_block_bound,
-    )
-
-    order = np.argsort(receivers[:e_real], kind="stable")
+def sort_edges_by_receiver(
+    senders, receivers, edge_mask, edge_payloads, e_real
+) -> bool:
+    """Bring the REAL edges into receiver order IN PLACE (padding edges
+    already target the first padding node, which sorts after every real
+    receiver): a check for monotone receivers first, a stable sort of
+    every edge-aligned array only when it fails. Returns whether
+    anything moved."""
+    rcv = receivers[:e_real]
+    if e_real < 2 or bool(np.all(rcv[1:] >= rcv[:-1])):
+        return False
+    order = np.argsort(rcv, kind="stable")
     for arr in (senders, receivers, edge_mask):
         arr[:e_real] = arr[:e_real][order]
     for arr in edge_payloads.values():
         if arr is not None:
             arr[:e_real] = arr[:e_real][order]
+    return True
+
+
+def apply_segment_plan(senders, receivers, edge_mask, edge_payloads, e_real, N):
+    """Sort REAL edges by receiver IN PLACE (``sort_edges_by_receiver``)
+    and build the static-size block plan for the Pallas aggregation
+    kernel. ``N`` is the padded node count; returns (seg_perm, seg_ids,
+    seg_valid, seg_window)."""
+    from hydragnn_tpu.ops.pallas_segment import (
+        plan_blocks_static,
+        static_block_bound,
+    )
+
+    sort_edges_by_receiver(senders, receivers, edge_mask, edge_payloads, e_real)
     b_max = static_block_bound(receivers.shape[0], N)
     # The edge mask is FOLDED INTO the plan's valid slots: padding
     # edges never enter the in-kernel gather, so the aggregation ops
     # need no pre-masked copy of the edge data (the HBM write the
     # fused kernel exists to avoid).
     return plan_blocks_static(receivers, N, b_max, edge_valid=edge_mask)
+
+
+def order_and_plan_edges(
+    senders, receivers, edge_mask, edge_payloads, e_real, N, *,
+    with_segment_plan: bool, sorted_receivers: bool,
+) -> dict:
+    """What collation does to a batch's edges once they are laid out,
+    IN PLACE, as the GraphBatch fields it decides: the block plan
+    (``apply_segment_plan``, which sorts) where one is wanted, else the
+    sort alone where the spec asks for sorted receivers, and the
+    promise ``receivers_sorted`` after either. The ONE implementation
+    shared by ``collate`` and the packed collators (data/pipeline.py),
+    whose contract is bit-identity with it."""
+    plan = (None, None, None, None)
+    if with_segment_plan:
+        plan = apply_segment_plan(
+            senders, receivers, edge_mask, edge_payloads, e_real, N
+        )
+    elif sorted_receivers:
+        sort_edges_by_receiver(
+            senders, receivers, edge_mask, edge_payloads, e_real
+        )
+    return dict(
+        zip(("seg_perm", "seg_ids", "seg_valid", "seg_window"), plan),
+        receivers_sorted=bool(with_segment_plan or sorted_receivers),
+    )
 
 
 def fill_triplets(t_kj, t_ji, triplet_mask, senders, receivers, e_real, n_real):
@@ -504,6 +549,9 @@ class PadSpec:
     num_edges: int
     num_graphs: int
     num_triplets: Optional[int] = None  # None = do not build triplets
+    # collation brings the edges into receiver order and the batch says
+    # so (GraphBatch.receivers_sorted)
+    sorted_receivers: bool = False
 
     @staticmethod
     def for_samples(
@@ -683,11 +731,11 @@ def collate(
     # (masked out of max_nodes_per_graph and dense layouts).
     node_slot[node_off:] = np.arange(N - node_off)
 
-    seg_perm = seg_ids = seg_valid = seg_window = None
-    if with_segment_plan:
-        seg_perm, seg_ids, seg_valid, seg_window = apply_segment_plan(
-            senders, receivers, edge_mask, edge_payloads, e_real, N
-        )
+    edge_plans = order_and_plan_edges(
+        senders, receivers, edge_mask, edge_payloads, e_real, N,
+        with_segment_plan=with_segment_plan,
+        sorted_receivers=pad.sorted_receivers,
+    )
 
     t_kj = t_ji = triplet_mask = None
     if pad.num_triplets is not None:
@@ -723,10 +771,7 @@ def collate(
         t_kj=t_kj,
         t_ji=t_ji,
         triplet_mask=triplet_mask,
-        seg_perm=seg_perm,
-        seg_ids=seg_ids,
-        seg_valid=seg_valid,
-        seg_window=seg_window,
+        **edge_plans,
     )
     if as_numpy:
         return batch

@@ -10,6 +10,7 @@ reference train_validate_test.py:671-672).
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Iterator, List, Optional, Sequence
 
 import numpy as np
@@ -66,6 +67,7 @@ class GraphLoader:
         pack_slack: Optional[float] = None,
         pack_max_graphs: Optional[int] = None,
         pack_dp_shards: int = 0,
+        sort_receivers: bool = False,
     ):
         """``num_samples`` resamples each epoch to a fixed size — the
         reference's oversampling RandomSampler (load_data.py:240-250),
@@ -120,6 +122,14 @@ class GraphLoader:
         shapes where the kernel beats the XLA scatter per the
         ROOFLINE-seeded crossover table
         (ops/pallas_segment.planned_profitable).
+
+        ``sort_receivers`` puts ``sorted_receivers`` on every spec of
+        the epoch plan: collation then keeps each batch's edges in
+        receiver order (a check for monotone receivers first, a stable
+        sort only where it fails) and the batch carries the promise
+        (``GraphBatch.receivers_sorted``), which the receiver
+        aggregation hands to XLA's scatter (ops/segment.py). It changes
+        no shape.
         """
         # Dataset OBJECTS (BinDataset, SimplePickleDataset, ...) pass
         # through unmaterialized — __iter__ indexes them per batch, so a
@@ -145,6 +155,7 @@ class GraphLoader:
         self.drop_last = drop_last
         self.with_triplets = with_triplets
         self.with_segment_plan = with_segment_plan
+        self.sort_receivers = bool(sort_receivers)
         self._seed = int(seed)
         self._epoch = 0
         self._skip_next = 0
@@ -481,6 +492,13 @@ class GraphLoader:
         spec = the bin's budget shape); with packing OFF this method is
         bit-identical to the pre-packing behavior.
         """
+        for idx, spec in self._planned_specs(epoch):
+            if self.sort_receivers and spec is not None:
+                spec = dataclasses.replace(spec, sorted_receivers=True)
+            yield idx, spec
+
+    def _planned_specs(self, epoch: int) -> Iterator[tuple]:
+        """``epoch_plan`` before ``sort_receivers``."""
         if self.packing:
             for idx, budget in self._packed_plan(epoch):
                 yield idx, budget.pad_spec()
@@ -548,7 +566,10 @@ class GraphLoader:
     def batch_spec(self, samples: Sequence[GraphSample]) -> PadSpec:
         """Spec for a planned batch whose ``epoch_plan`` entry was
         ``None`` (triplet ladder): each batch buckets independently."""
-        return PadSpec.for_samples(samples, with_triplets=self.with_triplets)
+        return dataclasses.replace(
+            PadSpec.for_samples(samples, with_triplets=self.with_triplets),
+            sorted_receivers=self.sort_receivers,
+        )
 
     def collate_entry(
         self, idx, spec, *, as_numpy: bool = False

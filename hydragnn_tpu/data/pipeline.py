@@ -181,17 +181,17 @@ def _plans_into_buffers(
     n_real: int,
     N: int,
 ):
-    """Segment plan + triplet padding via the SAME graph.py helpers
-    ``collate`` uses (bit-identity by construction); triplet buffers
-    come from the reuse pool. Returns (seg_perm, seg_ids, seg_valid,
-    seg_window, t_kj, t_ji, triplet_mask)."""
-    from hydragnn_tpu.data.graph import apply_segment_plan, fill_triplets
+    """Receiver order, segment plan + triplet padding via the SAME
+    graph.py helpers ``collate`` uses (bit-identity by construction);
+    triplet buffers come from the reuse pool. Returns the GraphBatch
+    fields they fill, by name."""
+    from hydragnn_tpu.data.graph import fill_triplets, order_and_plan_edges
 
-    seg_perm = seg_ids = seg_valid = seg_window = None
-    if with_segment_plan:
-        seg_perm, seg_ids, seg_valid, seg_window = apply_segment_plan(
-            senders, receivers, edge_mask, edge_payloads, e_real, N
-        )
+    edge_plans = order_and_plan_edges(
+        senders, receivers, edge_mask, edge_payloads, e_real, N,
+        with_segment_plan=with_segment_plan,
+        sorted_receivers=pad.sorted_receivers,
+    )
     t_kj = t_ji = triplet_mask = None
     if pad.num_triplets is not None:
         T = pad.num_triplets
@@ -201,7 +201,9 @@ def _plans_into_buffers(
         fill_triplets(
             t_kj, t_ji, triplet_mask, senders, receivers, e_real, n_real
         )
-    return seg_perm, seg_ids, seg_valid, seg_window, t_kj, t_ji, triplet_mask
+    return dict(
+        edge_plans, t_kj=t_kj, t_ji=t_ji, triplet_mask=triplet_mask
+    )
 
 
 def _concat_into(dst: np.ndarray, arrs: List[np.ndarray]) -> None:
@@ -441,19 +443,17 @@ def collate_packed(
         (s.dataset_id for s in samples), np.int64, count=g_real
     )
 
-    seg_perm, seg_ids, seg_valid, seg_window, t_kj, t_ji, triplet_mask = (
-        _plans_into_buffers(
-            out,
-            pad,
-            with_segment_plan,
-            senders,
-            receivers,
-            edge_mask,
-            edge_payloads,
-            e_real,
-            n_real,
-            N,
-        )
+    plans = _plans_into_buffers(
+        out,
+        pad,
+        with_segment_plan,
+        senders,
+        receivers,
+        edge_mask,
+        edge_payloads,
+        e_real,
+        n_real,
+        N,
     )
 
     return GraphBatch(
@@ -477,13 +477,7 @@ def collate_packed(
         cell=cell,
         energy=energy,
         forces=forces,
-        t_kj=t_kj,
-        t_ji=t_ji,
-        triplet_mask=triplet_mask,
-        seg_perm=seg_perm,
-        seg_ids=seg_ids,
-        seg_valid=seg_valid,
-        seg_window=seg_window,
+        **plans,
     )
 
 
@@ -498,6 +492,14 @@ def _stack_group(batches: List[GraphBatch], out: Dict[str, np.ndarray]) -> Macro
     fields = {}
     for f in _dc.fields(GraphBatch):
         xs = [getattr(b, f.name) for b in batches]
+        if not f.metadata.get("pytree_node", True):
+            # static metadata (receivers_sorted): one value for the group
+            if any(x != xs[0] for x in xs):
+                raise ValueError(
+                    f"superstep group mixes values of `{f.name}`"
+                )
+            fields[f.name] = xs[0]
+            continue
         if xs[0] is None:
             if any(x is not None for x in xs):
                 raise ValueError(
@@ -798,15 +800,7 @@ class PackedStore:
         dataset_id[g_real:] = 0
         dataset_id[:g_real] = self.tables["dataset_id"][idx]
 
-        (
-            seg_perm,
-            seg_ids,
-            seg_valid,
-            seg_window,
-            t_kj,
-            t_ji,
-            triplet_mask,
-        ) = _plans_into_buffers(
+        plans = _plans_into_buffers(
             out,
             pad,
             with_segment_plan,
@@ -840,13 +834,7 @@ class PackedStore:
             cell=cell,
             energy=energy,
             forces=forces,
-            t_kj=t_kj,
-            t_ji=t_ji,
-            triplet_mask=triplet_mask,
-            seg_perm=seg_perm,
-            seg_ids=seg_ids,
-            seg_valid=seg_valid,
-            seg_window=seg_window,
+            **plans,
         )
 
 

@@ -3,12 +3,19 @@
 TPU-native replacement for torch_scatter/torch_sparse segment ops
 (reference dep: requirements-pyg.txt; used by every PyG conv in
 hydragnn/models/*). Built on ``jax.ops.segment_*`` with static
-``num_segments`` so XLA lowers them to one-hot matmuls / sorted scatters
-that tile onto the MXU.
+``num_segments``. On the v5e XLA lowers those to a sort of the indices
+(once a step and index array) and a scatter-add fusion that reads the
+update rows through the sort's permutation and adds them one after
+another, at 38-56 GB/s (PERF.md sections 5 and 6). Told that the
+indices are sorted it skips the sort and reads the rows in place: the
+same additions in the same order in two thirds of the time, so the
+receiver aggregation tells it where collation has sorted the edges
+(``_sum_at_receivers``).
 """
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
 
 import jax
@@ -23,10 +30,15 @@ def segment_sum(
     segment_ids: jax.Array,
     num_segments: int,
     mask: Optional[jax.Array] = None,
+    *,
+    indices_are_sorted: bool = False,
 ) -> jax.Array:
     if mask is not None:
         data = jnp.where(_bcast(mask, data), data, 0)
-    return jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
+    return jax.ops.segment_sum(
+        data, segment_ids, num_segments=num_segments,
+        indices_are_sorted=indices_are_sorted,
+    )
 
 
 @tr.scoped("segment/mean")
@@ -246,6 +258,54 @@ def _plan_dispatch(
     )
 
 
+# Receiver-aggregation call sites by the scatter they lowered to, counted
+# while a program is traced (the choice compiles away): ``dispatch_counter``
+# around a jitted function's body writes them as one telemetry row.
+_DISPATCH = {"sorted_scatter": 0, "scatter": 0}
+
+
+@contextlib.contextmanager
+def dispatch_counter(program: str):
+    """Trace-time: count the ``aggregate_receivers*`` call sites inside
+    that reach XLA's scatter by whether they could promise it sorted
+    indices, and write one row ``{"t": "setup", "phase":
+    "segment_dispatch", "program": ..., "sorted_scatter": n, "scatter":
+    m}`` to the live telemetry stream."""
+    from hydragnn_tpu.utils import telemetry
+
+    before = dict(_DISPATCH)
+    try:
+        yield
+    finally:
+        if telemetry.active():
+            telemetry.emit(
+                {
+                    "t": "setup",
+                    "phase": "segment_dispatch",
+                    "program": program,
+                    **{k: _DISPATCH[k] - before[k] for k in _DISPATCH},
+                }
+            )
+
+
+def _sum_at_receivers(msg: jax.Array, batch) -> jax.Array:
+    """The masked receiver sum by XLA's scatter-add, told that the
+    indices are sorted where the batch promises it
+    (``GraphBatch.receivers_sorted``, collation's word). On the v5e the
+    sorted scatter adds each node's rows in the same order as the plain
+    one, bit for bit, in 7.9 against 13.7 ms at E=790,776 F=128 and 6.8
+    against 13.7 ms at E=568,056 F=256 (my chip run 4, PR 34): the one
+    faster aggregation found that keeps that order, which the
+    benchmark's ``correct`` holds the program to (PERF.md section 6)."""
+    # a batch-like that says nothing (a hand-made namespace) promises nothing
+    promised = bool(getattr(batch, "receivers_sorted", False))
+    _DISPATCH["sorted_scatter" if promised else "scatter"] += 1
+    return segment_sum(
+        msg, batch.receivers, batch.num_nodes, mask=batch.edge_mask,
+        indices_are_sorted=promised,
+    )
+
+
 @tr.scoped("edge_aggregate")
 def aggregate_receivers(
     msg: jax.Array, batch, *, use_plan: Optional[bool] = None
@@ -257,10 +317,12 @@ def aggregate_receivers(
     TPU, AND the padded shape is on the kernel's winning side of the
     measured crossover table (``_plan_dispatch``) — or anywhere when
     HYDRAGNN_TPU_SEGMENT_IMPL=pallas[_fused] forces it (interpret mode
-    off-TPU); falls back to the XLA scatter path otherwise. Both apply
-    the edge mask — on the planned path it is FOLDED INTO the plan's
-    ``valid`` slots at collate time (apply_segment_plan), so no masked
-    copy of ``msg`` is materialized ahead of the in-kernel gather.
+    off-TPU); falls back to the XLA scatter path otherwise
+    (``_sum_at_receivers``: the sorted scatter where the batch promises
+    sorted receivers). Both apply the edge mask — on the planned path
+    it is FOLDED INTO the plan's ``valid`` slots at collate time
+    (apply_segment_plan), so no masked copy of ``msg`` is materialized
+    ahead of the in-kernel gather.
     """
     if use_plan is None:
         use_plan = _plan_dispatch(batch, feature_dim=msg.shape[-1])
@@ -275,9 +337,7 @@ def aggregate_receivers(
             batch.seg_window,
             batch.num_nodes,
         )
-    return segment_sum(
-        msg, batch.receivers, batch.num_nodes, mask=batch.edge_mask
-    )
+    return _sum_at_receivers(msg, batch)
 
 
 @tr.scoped("edge_aggregate")
@@ -316,9 +376,7 @@ def aggregate_receivers_product(
                 batch.num_nodes,
             )
         return aggregate_receivers(a * b, batch, use_plan=True)
-    return segment_sum(
-        a * b, batch.receivers, batch.num_nodes, mask=batch.edge_mask
-    )
+    return _sum_at_receivers(a * b, batch)
 
 
 @tr.scoped("edge_aggregate")

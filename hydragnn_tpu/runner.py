@@ -778,12 +778,18 @@ def _run_training(config_source, datasets, seed, setup):
                     else 0
                 ),
             )
+            # Receiver-sorted edges with the batch saying so, for XLA's
+            # sorted scatter in the aggregation (ops/segment.py). Single
+            # scheme only, like the block plans: the other schemes'
+            # collators promise nothing, and their batches keep the
+            # plain scatter.
+            sort_receivers = plan.scheme == "single"
             if which == 0:
                 return GraphLoader(
                     dataset, batch_size, shuffle=True, seed=seed,
                     with_triplets=trips, fixed_pad=fp,
                     with_segment_plan=seg_plan, ensure_fields=ensure,
-                    spec_schedule=sched,
+                    spec_schedule=sched, sort_receivers=sort_receivers,
                     pack_budgets=pack_budgets if packed else None,
                     **pack_kw,
                 )
@@ -795,7 +801,8 @@ def _run_training(config_source, datasets, seed, setup):
                 fixed_pad=fp, with_segment_plan=seg_plan,
                 ensure_fields=ensure,
                 cache_batches=isinstance(dataset, list),
-                spec_schedule=sched, **pack_kw,
+                spec_schedule=sched, sort_receivers=sort_receivers,
+                **pack_kw,
             )
 
         split_sets = (trainset_p, valset_p, testset_p)

@@ -13,6 +13,7 @@ fetched epoch metrics — everything inside the step functions is static.
 
 from __future__ import annotations
 
+import functools
 import os
 import time
 from collections.abc import Mapping
@@ -28,6 +29,7 @@ from hydragnn_tpu.data.graph import GraphBatch
 from hydragnn_tpu.data.loader import GraphLoader
 from hydragnn_tpu.models.base import MultiHeadGraphModel
 from hydragnn_tpu.models.spec import ModelConfig
+from hydragnn_tpu.ops.segment import dispatch_counter
 from hydragnn_tpu.train.losses import multihead_loss
 from hydragnn_tpu.train.mlip import (
     energy_and_forces,
@@ -105,6 +107,20 @@ def make_eval_loss_fn(
     return loss_fn
 
 
+def _dispatch_counted(fn: Callable) -> Callable:
+    """``fn`` for ``jax.jit``, under the same name: while it is traced,
+    its segment-op call sites are counted by the path each took and
+    written as the program's ``segment_dispatch`` telemetry row
+    (ops/segment.dispatch_counter)."""
+
+    @functools.wraps(fn)
+    def counted(*args):
+        with dispatch_counter(f"jit_{fn.__name__}"):
+            return fn(*args)
+
+    return counted
+
+
 def make_train_step(
     model: MultiHeadGraphModel,
     tx,
@@ -165,6 +181,7 @@ def make_train_step(
             return state, tot, tasks, ng, ok, gnorm
         return new_state, tot, tasks
 
+    train_step = _dispatch_counted(train_step)
     if donate:
         return jax.jit(train_step, donate_argnums=0)
     return jax.jit(train_step)
@@ -184,12 +201,11 @@ def make_eval_step(
         model, cfg, compute_grad_energy, collect_outputs
     )
 
-    @jax.jit
     def eval_step(state: TrainState, batch: GraphBatch):
         b = cast_batch(batch, compute_dtype)
         return loss_fn(state.params, state.batch_stats, b)
 
-    return eval_step
+    return jax.jit(_dispatch_counted(eval_step))
 
 
 def fold_step_metrics(acc, tots, tasks, gs):
@@ -307,6 +323,7 @@ def make_superstep_fn(
             state, (tots, tasks, gs) = jax.lax.scan(body, state, batches)
             return state, fold_step_metrics(acc, tots, tasks, gs)
 
+        train_superstep = _dispatch_counted(train_superstep)
         if donate:
             return jax.jit(train_superstep, donate_argnums=(0, 1))
         return jax.jit(train_superstep)
@@ -325,6 +342,7 @@ def make_superstep_fn(
 
     # Eval never donates the (reused) state; the accumulator is rebound
     # every call, so its buffers recycle through the donation.
+    eval_superstep = _dispatch_counted(eval_superstep)
     if donate:
         return jax.jit(eval_superstep, donate_argnums=(1,))
     return jax.jit(eval_superstep)

@@ -124,6 +124,61 @@ def test_edge_aggregate_plain_and_under_transpose(lowered):
     assert not any("transpose(" in p or "/optimizer/" in p for p in ev)
 
 
+@pytest.mark.parametrize("promised", [True, False])
+def test_sorted_scatter_keeps_the_scope_and_reports_its_dispatch(
+    tmp_path, promised
+):
+    """ISSUE 34: with receiver-sorted batches the layers' scatters lower
+    with ``indices_are_sorted`` under the same scope as before,
+    ``edge_aggregate/segment/sum``, so the per-layer metrics go on
+    reading the same block; the program's ``segment_dispatch`` row says
+    how many of its call sites could make the promise."""
+    from hydragnn_tpu.config import update_config
+    from hydragnn_tpu.data.loader import GraphLoader
+    from hydragnn_tpu.models.create import create_model_config, init_params
+    from hydragnn_tpu.train import loop
+    from hydragnn_tpu.train.optimizer import select_optimizer
+    from hydragnn_tpu.train.state import create_train_state
+
+    samples = _mols(16, seed=3)
+    cfgd = update_config(_config(), samples)
+    model, cfg = create_model_config(cfgd)
+    batch = next(iter(GraphLoader(samples, 4, sort_receivers=promised)))
+    assert batch.receivers_sorted is promised
+    params, bs = init_params(model, batch)
+    tx = select_optimizer(cfgd["NeuralNetwork"]["Training"])
+    state = create_train_state(params, tx, bs)
+    stream = telemetry.TelemetryStream(str(tmp_path / "t.jsonl"))
+    telemetry.install(stream)
+    try:
+        text = loop.make_train_step(model, tx, cfg, donate=False).lower(
+            state, batch
+        ).as_text(debug_info=True)
+    finally:
+        telemetry.close_run(stream)
+    (row,) = [
+        r for r in _rows(str(tmp_path / "t.jsonl"))
+        if r.get("phase") == "segment_dispatch"
+    ]
+    sites = cfg.num_conv_layers  # one reduce a layer
+    assert row["t"] == "setup" and row["program"] == "jit_train_step"
+    assert (row["sorted_scatter"], row["scatter"]) == (
+        (sites, 0) if promised else (0, sites)
+    )
+    # each layer's forward reduce is the one scatter that carries the
+    # promise (the sender gather's transpose is a scatter too, unsorted),
+    # and it sits where the parent's did
+    sorted_scatters = re.findall(
+        r'"stablehlo.scatter"\([^\n]*indices_are_sorted = true', text
+    )
+    assert len(sorted_scatters) == (sites if promised else 0)
+    reduces = [
+        p for p in _under(_paths(text), "edge_aggregate")
+        if p.endswith("/segment/sum/scatter-add") and "transpose(" not in p
+    ]
+    assert len(reduces) == sites
+
+
 def test_scope_names_are_a_vocabulary():
     def f(x):
         with tr.scope("edge_aggregate"):

@@ -301,8 +301,8 @@ def make_molecules(n_graphs: int, seed: int):
 
 
 def schnet_config(size: dict, name: str, epochs: int = 2) -> dict:
-    """bench.py's headline SchNet (schnet_qm9scale) with every Training
-    option at its default: fp32, pipeline feed, packing auto, superstep
+    """A SchNet at the PyG QM9 widths with every Training option at
+    its default: fp32, pipeline feed, packing auto, superstep
     auto, use_segment_plan auto."""
     h = size["hidden"]
     return {
@@ -449,12 +449,12 @@ def phase_train(size: dict, seed: int, work: str):
         f"split {[len(s) for s in splits]}")
     log(f"model: SchNet hidden {size['hidden']}, {size['hidden']} filters, "
         f"{size['gaussians']} Gaussians, {size['layers']} interaction "
-        f"layers, batch {size['batch']}, fp32 — width of bench.py's "
-        "schnet_qm9scale headline. Hidden/filters/Gaussians are the "
+        f"layers, batch {size['batch']}, fp32. "
+        "Hidden/filters/Gaussians are the "
         "PyG SchNet defaults HydraGNN wraps for QM9 (128/128/50, from "
         "memory: no network here); the published depth is 6 interaction "
         "blocks at a 10 A cutoff, cut here to 4 layers at 4 A / 32 "
-        "neighbours as the headline configuration has it.")
+        "neighbours: a smoke run, not the benchmark's schnet_qm9.")
 
     dump = os.path.join(work, "ir")
     config = schnet_config(size, "chip_smoke")

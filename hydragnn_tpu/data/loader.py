@@ -287,8 +287,8 @@ class GraphLoader:
     def packing_stats(self, epoch: Optional[int] = None) -> Optional[dict]:
         """Fill/waste arithmetic of one epoch's packed plan (None when
         packing is off): batch count, node/edge fill fractions, and the
-        size-linear pad ratio executed/real — the loader-side number
-        bench.py's ``packed_batching`` config reports."""
+        size-linear pad ratio executed/real (the loop's
+        ``pack_pad_ratio`` sample reads it)."""
         if not self.packing or not self.pack_budgets:
             return None
         plan = self._packed_plan(self._epoch if epoch is None else epoch)

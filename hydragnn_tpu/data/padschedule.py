@@ -328,8 +328,8 @@ def _default_bucket_limit() -> int:
 # budgets from the size histogram, then first-fit-decreasing pack each
 # epoch's graphs into them. Device-free size arithmetic throughout, like
 # the spec schedules above — the packing residual replaces the ladder's
-# growth-factor padding waste (BENCH_TPU.json measured pad_ratio 1.443
-# on the pnaplus_gps_zinc ladder; packing targets ~1.05).
+# growth-factor padding waste (the ladder's buckets grow by a factor,
+# so a batch can pad up to that factor; packing pads by its slack).
 # ----------------------------------------------------------------------
 
 
@@ -1052,8 +1052,8 @@ def packing_beats_ladder(
     per-batch buckets), ``"worst"`` (forced single worst-case spec),
     or ``"auto"``: the bucket ladder while its distinct-shape count
     stays within HYDRAGNN_TPU_MAX_PAD_BUCKETS, else the worst-case
-    clamp — exactly the high-variance regime (BENCH_TPU's 1.443)
-    where packing wins most."""
+    clamp — exactly the high-variance regime where packing wins
+    most."""
     node_sizes = np.asarray(node_sizes, dtype=np.int64)
     edge_sizes = np.asarray(edge_sizes, dtype=np.int64)
     if len(node_sizes) == 0:

@@ -1,10 +1,10 @@
 """Parallel input pipeline: multi-worker collation, packed batch
 assembly, double-buffered device transfer, and feed telemetry.
 
-Round-5 benchmarks put the jitted SchNet step at 135k+ graphs/s while
-``run_training`` delivered ~1.5k graphs/s end-to-end: a single collate
-thread producing ~86 ms batches cannot feed a 0.54 ms device step
-(VERDICT.md / BENCH_r05.json). This module is the fix — the TPU-native
+The round-5 verdict (VERDICT.md) found ``run_training`` two orders of
+magnitude behind its own jitted step at a small model: a single collate
+thread cannot feed a device step of well under a millisecond. This
+module is the fix — the TPU-native
 analog of the reference's ThreadPoolExecutor + CPU-affinity loader
 (hydragnn/preprocess/load_data.py:94-204), restructured around the
 deterministic pad plan the static-shape batching already requires:
@@ -29,8 +29,8 @@ deterministic pad plan the static-shape batching already requires:
 - **Telemetry**: per-epoch collate latency, H2D latency, reorder-queue
   depth, and a starved-step counter (consumer blocked waiting for the
   next batch), accumulated on ``PipelineStats`` and mirrored into
-  ``hydragnn_tpu.utils.tracer`` rows so ``bench.py`` and the trace CSV
-  expose input-boundness directly.
+  ``hydragnn_tpu.utils.tracer`` rows so the trace CSV exposes
+  input-boundness directly.
 
 Buffer-reuse contract (packed mode): a yielded batch's arrays stay
 valid for at least ``hold`` further deliveries (default 2 — current +

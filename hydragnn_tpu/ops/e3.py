@@ -314,7 +314,7 @@ def wigner_d_from_sh(l: int, rot: np.ndarray) -> np.ndarray:
     ``rot`` would otherwise poison ``v @ rot.T`` — numpy's matmul
     defers to ``jax.Array.__rmatmul__``, the whole pipeline silently
     drops to fp32, and the 1e-6 verification tolerance (calibrated for
-    fp64 lstsq residuals) becomes unreachable (BENCH_TPU.json:
+    fp64 lstsq residuals) becomes unreachable (seen on a TPU as
     ``Wigner D fit failed for l=1: err 0.00599`` — a float32-precision
     error magnitude).
     """

@@ -21,9 +21,9 @@ slot-waste — with four GATES:
 - ``complete``: every submitted request came back with a response —
   percentiles over a stream that dropped responses would gate a lie.
 
-Run directly (``python -m hydragnn_tpu.serve.loadgen --json``) or via
-bench.py's ``online_serving`` row; the ``serving_smoke`` entry leg
-(__graft_entry__.py) runs a bounded variant in the verify flow.
+Run directly (``python -m hydragnn_tpu.serve.loadgen --json``); the
+``serving_smoke`` entry leg (__graft_entry__.py) runs a bounded variant
+in the verify flow.
 """
 
 from __future__ import annotations

@@ -210,8 +210,8 @@ def macro_plan(n_steps: int, superstep_k: int) -> List[int]:
     """Per-dispatch trip counts for a clean rollout of ``n_steps``:
     the exact chunking ``RolloutEngine.run`` walks when no containment
     event fires — full K macros plus one shorter tail. Pure host
-    arithmetic; the bench's device-free dispatch-count gate reads it
-    and then asserts a real rollout dispatched exactly this plan."""
+    arithmetic; tests/test_simulate.py holds a real rollout's
+    dispatches to exactly this plan."""
     k = max(1, int(superstep_k))
     out: List[int] = []
     left = int(n_steps)

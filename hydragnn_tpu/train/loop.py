@@ -714,8 +714,7 @@ def _run_epoch(
             float(pstats.starved_steps - starved_before),
         )
     # Bin-packing telemetry: the epoch's size-linear pad ratio and
-    # node/edge fill, when the feed chain packs (data/loader.py) — the
-    # live counterpart of bench.py's packed_batching arithmetic.
+    # node/edge fill, when the feed chain packs (data/loader.py).
     from hydragnn_tpu.data.loader import loader_packing_stats
 
     pack = loader_packing_stats(loader)

@@ -1,47 +1,41 @@
 """Analytic model-FLOPs estimates + device peak tables + the shared
 parsers for XLA's per-executable cost/memory accounting.
 
-THE single source of flop arithmetic shared by ``bench.py`` (the
-offline ``model_flops_per_graph`` / ``mfu`` anchors) and the run
-telemetry subsystem (``utils/telemetry.py``'s live per-spec MFU rows,
-docs/OBSERVABILITY.md): the live metric and the bench metric must be
-the same function of the same inputs, or "MFU went up" is an
-accounting artifact. Each estimator is a dense multiply-add inventory
-(x2 = FLOPs) over MEAN REAL node/edge sizes — no padding, no scatter
-lowering — i.e. the implementation-independent figure a fair
-cross-framework comparison divides by (bench.py header).
+The flop arithmetic behind the run telemetry subsystem
+(``utils/telemetry.py``'s live per-spec MFU rows,
+docs/OBSERVABILITY.md), its one reader. Each estimator is a dense
+multiply-add inventory (x2 = FLOPs) over MEAN REAL node/edge sizes —
+no padding, no scatter lowering — i.e. the implementation-independent
+figure a fair cross-framework comparison divides by. The benchmark
+keeps counts of its own under ``benchmarks/counts/`` (``train_mfu``
+reads those, not these).
 
-The same single-source rule applies to the COUNTED side:
-``compiled_cost_stats`` / ``compiled_memory_stats`` parse
-``jax.stages.Compiled.cost_analysis()`` / ``memory_analysis()`` into
-plain dicts — shared by bench.py's offline flops/step capture and the
-telemetry subsystem's per-executable ``executable`` rows, so the
-"hardware flops" both report are the same parse of the same XLA
-estimate. The analytic/counted PAIR is what roofline attribution
-needs: counted/analytic is the padding+lowering waste factor, and
-counted flops over counted bytes is the arithmetic intensity the
-roofline ceiling ``min(peak_flops, intensity * peak_bw)`` turns into
-a memory-bound/compute-bound verdict (tools/graftboard.py roofline).
+The COUNTED side: ``compiled_cost_stats`` / ``compiled_memory_stats``
+parse ``jax.stages.Compiled.cost_analysis()`` / ``memory_analysis()``
+into plain dicts for the telemetry subsystem's per-executable
+``executable`` rows. The analytic/counted PAIR is what roofline
+attribution needs: counted/analytic is the padding+lowering waste
+factor, and counted flops over counted bytes is the arithmetic
+intensity the roofline ceiling ``min(peak_flops, intensity * peak_bw)``
+turns into a memory-bound/compute-bound verdict (tools/graftboard.py
+roofline).
 
 Peak resolution (``resolve_peak_flops`` / ``resolve_peak_bandwidth``):
 the running chip's ``device_kind`` when the tables know it. A chip the
 tables do not know is an ERROR, never a default — its utilization
 would be computed against some other chip's peak. Only a CPU run (or
-one with no backend up yet) falls back to the ROOFLINE anchor device
-parsed from ``ROOFLINE_TPU.txt``, flagged ``roofline_anchor`` — a
-what-if "MFU this run would achieve on the anchor TPU" that is never
-a device metric; when the anchor is absent too, callers get
-(None, None) and must omit the metric.
+one with no backend up yet) falls back to ``ANCHOR_DEVICE_KIND``, the
+benchmark's chip, flagged ``roofline_anchor`` — a what-if "MFU this
+run would achieve on the anchor TPU" that is never a device metric
+(the CPU tests of the MFU and roofline arithmetic stand on it).
 """
 
 from __future__ import annotations
 
 import math
-import os
 from typing import Optional, Tuple
 
 # Peak bf16 FLOPs/sec by jax device_kind (public TPU/GPU specs).
-# bench.py imports this table; keep the two consumers on one copy.
 PEAK_FLOPS = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
@@ -64,101 +58,47 @@ PEAK_HBM_BYTES_PER_SEC = {
     "TPU v6e": 1640e9,
 }
 
-_ROOFLINE_CACHE: dict = {}
+# The chip a CPU run's what-if peaks are read against: the device kind
+# of the benchmark's chip (BENCHMARK.json's cells run on one of these).
+ANCHOR_DEVICE_KIND = "TPU v5 lite"
 
 
-def roofline_anchor(path: Optional[str] = None) -> Optional[dict]:
-    """Parse the ROOFLINE_TPU.txt header into ``{"device_kind": str,
-    "hbm_peak_gbps": float}`` (None when the capture is absent). The
-    file's first line reads ``device: <kind>  peak HBM: <N> GB/s``;
-    override the location with ``HYDRAGNN_TPU_ROOFLINE``."""
-    if path is None:
-        path = os.environ.get("HYDRAGNN_TPU_ROOFLINE") or os.path.join(
-            os.path.dirname(
-                os.path.dirname(
-                    os.path.dirname(os.path.abspath(__file__))
-                )
-            ),
-            "ROOFLINE_TPU.txt",
+def _resolve_peak(
+    table: dict, table_name: str, what: str, device_kind: Optional[str]
+) -> Tuple[float, str]:
+    if device_kind in table:
+        return table[device_kind], "device"
+    # a real chip (not a CPU run, not a backend that is down: kind None)
+    if device_kind is not None and device_kind.lower() != "cpu":
+        raise ValueError(
+            f"no peak {what} known for device kind {device_kind!r}: add "
+            f"it to hydragnn_tpu.utils.flops.{table_name} with its source"
         )
-    if path in _ROOFLINE_CACHE:
-        return _ROOFLINE_CACHE[path]
-    anchor = None
-    try:
-        with open(path) as f:
-            first = f.readline()
-        if first.startswith("device:"):
-            body = first[len("device:"):]
-            kind = body.split("peak HBM:")[0].strip()
-            hbm = None
-            if "peak HBM:" in body:
-                tok = body.split("peak HBM:")[1].strip().split()[0]
-                hbm = float(tok)
-            if kind:
-                anchor = {"device_kind": kind, "hbm_peak_gbps": hbm}
-    except (OSError, ValueError, IndexError):
-        anchor = None
-    _ROOFLINE_CACHE[path] = anchor
-    return anchor
-
-
-def _on_accelerator(device_kind: Optional[str]) -> bool:
-    """True for the kind of a real chip; False for a CPU run or one
-    whose backend is not up (kind None) — the only callers that may
-    see the ROOFLINE anchor's what-if peaks."""
-    return device_kind is not None and device_kind.lower() != "cpu"
+    return table[ANCHOR_DEVICE_KIND], "roofline_anchor"
 
 
 def resolve_peak_flops(
     device_kind: Optional[str] = None,
-) -> Tuple[Optional[float], Optional[str]]:
+) -> Tuple[float, str]:
     """(peak bf16 FLOPs/sec, basis) for MFU denominators. Basis
     ``"device"`` = the running chip is in the peak table (a real MFU).
     A chip that is NOT in the table raises. On a CPU run the basis is
-    ``"roofline_anchor"`` = ROOFLINE_TPU.txt's device (a what-if
-    utilization on the anchor chip, labelled as such), or (None, None)
-    when the anchor does not resolve."""
-    if device_kind in PEAK_FLOPS:
-        return PEAK_FLOPS[device_kind], "device"
-    if _on_accelerator(device_kind):
-        raise ValueError(
-            f"no peak FLOP/s known for device kind {device_kind!r}: add "
-            "it to hydragnn_tpu.utils.flops.PEAK_FLOPS with its source"
-        )
-    anchor = roofline_anchor()
-    if anchor is not None and anchor["device_kind"] in PEAK_FLOPS:
-        return PEAK_FLOPS[anchor["device_kind"]], "roofline_anchor"
-    return None, None
+    ``"roofline_anchor"`` = ``ANCHOR_DEVICE_KIND``'s peak (a what-if
+    utilization on the anchor chip, labelled as such)."""
+    return _resolve_peak(PEAK_FLOPS, "PEAK_FLOPS", "FLOP/s", device_kind)
 
 
 def resolve_peak_bandwidth(
     device_kind: Optional[str] = None,
-) -> Tuple[Optional[float], Optional[str]]:
+) -> Tuple[float, str]:
     """(peak HBM bytes/sec, basis) — the bandwidth axis of the
-    roofline. Basis semantics mirror ``resolve_peak_flops``:
-    ``"device"`` = the running chip is in the table; a chip that is
-    not raises; on a CPU run ``"roofline_anchor"`` = ROOFLINE_TPU.txt's
-    device (its own measured ``peak HBM`` header wins over the table
-    when present), or (None, None) when that does not resolve —
-    callers OMIT the ceiling, never estimate one."""
-    if device_kind in PEAK_HBM_BYTES_PER_SEC:
-        return PEAK_HBM_BYTES_PER_SEC[device_kind], "device"
-    if _on_accelerator(device_kind):
-        raise ValueError(
-            f"no peak HBM bandwidth known for device kind {device_kind!r}: "
-            "add it to hydragnn_tpu.utils.flops.PEAK_HBM_BYTES_PER_SEC "
-            "with its source"
-        )
-    anchor = roofline_anchor()
-    if anchor is not None:
-        if anchor.get("hbm_peak_gbps"):
-            return anchor["hbm_peak_gbps"] * 1e9, "roofline_anchor"
-        if anchor["device_kind"] in PEAK_HBM_BYTES_PER_SEC:
-            return (
-                PEAK_HBM_BYTES_PER_SEC[anchor["device_kind"]],
-                "roofline_anchor",
-            )
-    return None, None
+    roofline. Basis semantics mirror ``resolve_peak_flops``."""
+    return _resolve_peak(
+        PEAK_HBM_BYTES_PER_SEC,
+        "PEAK_HBM_BYTES_PER_SEC",
+        "HBM bandwidth",
+        device_kind,
+    )
 
 
 def compiled_cost_stats(compiled) -> dict:
@@ -169,8 +109,8 @@ def compiled_cost_stats(compiled) -> dict:
     ``bytes_accessed``, ``transcendentals``, ``optimal_seconds``.
     Returns {} when the backend publishes no cost model (some PJRT
     plugins) — callers must treat absence as "unknown", never 0.
-    The single parse shared by bench.py's flops/step capture and the
-    telemetry ``executable`` rows (docs/OBSERVABILITY.md)."""
+    The parse behind the telemetry ``executable`` rows
+    (docs/OBSERVABILITY.md)."""
     try:
         ca = compiled.cost_analysis()
     except Exception:
@@ -228,19 +168,20 @@ def compiled_memory_stats(compiled) -> dict:
 
 
 # ----------------------------------------------------------------------
-# Per-architecture inventories (moved verbatim from bench.py; docstrings
-# document the op accounting). All take mean REAL sizes n (nodes/graph)
-# and e (edges/graph).
+# Per-architecture inventories (docstrings document the op
+# accounting). All take mean REAL sizes n (nodes/graph) and e
+# (edges/graph).
 # ----------------------------------------------------------------------
 
 
 def schnet_flops(n, e, F, G, L, H):
     """SchNet forward multiply-adds (x2 = FLOPs) for n nodes / e edges:
     per conv layer the filter MLP on rbf (G->F->F per edge), cfconv
-    in/out projections (F*F per node, twice), message multiply and
-    segment add (F per edge each); then shared/head MLPs and the node
-    embed. x3 for forward+backward of a train step."""
-    fwd = L * (2 * e * (G * F + F * F) + 2 * n * (2 * F * F) + 2 * e * F)
+    in/out projections (``lin1`` H*F and ``lin2`` F*H per node),
+    message multiply and segment add (F per edge each); then
+    shared/head MLPs and the node embed. x3 for forward+backward of a
+    train step."""
+    fwd = L * (2 * e * (G * F + F * F) + 2 * n * (H * F + F * H) + 2 * e * F)
     fwd += 2 * n * H * H + 6 * H * H
     return 3.0 * fwd
 

@@ -3,7 +3,7 @@ MFU accounting, and the compile/retrace observer (docs/OBSERVABILITY.md).
 
 The framework's training claims — "the loop never blocks on a per-batch
 sync", "one compiled shape per budget", "8.35% MFU" — were only ever
-checkable offline (BENCH_TPU.json, end-of-run tracer CSVs). This module
+checkable offline (a benchmark's report, end-of-run tracer CSVs). This module
 makes them continuously observable DURING a run, under one discipline
 inherited from the checkpoint writer (utils/checkpoint.CheckpointWriter,
 docs/DURABILITY.md): telemetry must never block or perturb a training
@@ -31,11 +31,11 @@ step.
   from the loader's plan arithmetic (``epoch_size_rows`` — host
   metadata, no device work).
 
-- Live MFU: per-spec achieved FLOP/s from the SAME analytic model-flop
-  inventories bench.py anchors on (utils/flops.py), over the
-  plan-domain real sizes, divided by ``flops.resolve_peak_flops`` (the
-  running chip, or the ROOFLINE_TPU.txt anchor device on hosts without
-  a table entry — flagged by ``peak_basis``).
+- Live MFU: per-spec achieved FLOP/s from the analytic model-flop
+  inventories of utils/flops.py, over the plan-domain real sizes,
+  divided by ``flops.resolve_peak_flops`` (the running chip, or on a
+  CPU run the anchor chip ``flops.ANCHOR_DEVICE_KIND`` — flagged by
+  ``peak_basis``).
 
 - ``CompileObserver`` — registers ``jax.monitoring`` listeners to count
   XLA compilations + compile milliseconds, surface persistent-cache
@@ -253,7 +253,7 @@ def _jax_backend_initialized() -> bool:
     sys.modules`` is not enough — jax is imported transitively by the
     package, and ``jax.devices()`` on a merely-imported jax would
     INITIALIZE the default backend as a side effect of constructing a
-    stream, racing bench.py's platform probe or a pending
+    stream, racing a launcher's platform probe or a pending
     ``jax.distributed.initialize``. Unknowable (internals moved) reads
     as False: a header without device fields beats a hijacked
     backend."""
@@ -1190,7 +1190,7 @@ class StepClock:
     def _maybe_capture(self, fn, args, spec: str, k: int) -> None:
         """First-dispatch executable capture: AOT ``fn.lower(*args)
         .compile()`` of the SAME jitted step this dispatch ran, parsed
-        by the shared helpers bench.py uses (utils/flops.py) and
+        by the helpers of utils/flops.py and
         emitted as one versioned ``executable`` row. Runs ONCE per
         (region, spec, k, lanes) key — at warmup for the stable specs,
         at the leak's first dispatch for a post-warmup retrace (the
@@ -1361,7 +1361,7 @@ class StepClock:
             # intermediates), so a reader recomputing
             # ``flops(cfg, mean_nodes, mean_edges) * graphs / wall /
             # peak`` from the row reproduces ``mfu`` exactly — the
-            # 1e-9-relative consistency contract with bench.py's flop
+            # 1e-9-relative consistency contract with utils/flops.py's
             # arithmetic (tests/test_telemetry.py pins it).
             graphs = out["graphs"]
             wall_s = out["wall_ms"] / 1e3
@@ -1420,8 +1420,8 @@ class StepClock:
                     ):
                         # executed/analytic — the padding + lowering
                         # + recompute waste factor (>= 1 for plain
-                        # fwd+bwd; MLIP's 9x bound can read < 1,
-                        # bench.py's hw_vs_model_flops caveat).
+                        # fwd+bwd; MLIP's 9x is an upper bound, so
+                        # the quotient can read < 1 there).
                         out["hw_over_model_flops"] = out["hw_flops"] / (
                             out["model_flops_per_graph"] * graphs
                         )

@@ -228,6 +228,55 @@ def test_dp_step_plan_folds_and_flags_mixed_steps():
     assert [e[0] for e in tail] == [6]
 
 
+def test_packed_dp_plan_folds_4x_at_k8_and_pads_less_than_the_ladder():
+    """Plan arithmetic on an 8-device data mesh over zinc-like sizes:
+    the coordinated packer's spec-major steps fold at least 4x at K=8,
+    and its padded node+edge slots over real ones stay under those of
+    the dp spec-schedule ladder on the same shuffle."""
+    from hydragnn_tpu.data.loader import GraphLoader
+    from hydragnn_tpu.data.padschedule import (
+        batch_size_rows,
+        dataset_size_arrays,
+        dp_spec_schedule,
+        dp_step_plan,
+        epoch_batch_indices,
+        superstep_groups,
+    )
+
+    n_dev, batch_size = 8, 8
+    samples = _mols(256, 18, 38, seed=2) * 8
+    loader = GraphLoader(
+        samples, batch_size, shuffle=True, seed=0, packing=True,
+        pack_dp_shards=n_dev,
+    )
+    ns, es = dataset_size_arrays(samples)
+    sched = dp_spec_schedule(
+        ns, es, batch_size=batch_size, n_procs=1, steps_group=n_dev,
+        seed=0, shuffle=True,
+    )
+    for ep in range(2):
+        plan = list(loader.epoch_plan(ep))
+        steps, tail = dp_step_plan(plan, n_dev)
+        assert not tail
+        assert len(steps) >= 4 * len(superstep_groups(steps, 8))
+        packed = sum(s.num_nodes + s.num_edges for _, s in plan) / sum(
+            int(ns[idx].sum()) + int(es[idx].sum()) for idx, _ in plan
+        )
+        rows = batch_size_rows(
+            ns,
+            es,
+            epoch_batch_indices(
+                len(ns), batch_size, shuffle=True, seed=0, epoch=ep
+            ),
+        )
+        assert len(rows) % n_dev == 0  # no masked remainder step to count
+        specs = [sched.spec(ep, j) for j in range(len(rows))]
+        ladder = sum(s.num_nodes + s.num_edges for s in specs) / sum(
+            int(rn) - 1 + int(re_) for rn, re_, _ in rows
+        )
+        assert 1.0 <= packed < ladder
+
+
 # ----------------------------------------------------------------------
 # resolve_superstep_k under dp
 # ----------------------------------------------------------------------

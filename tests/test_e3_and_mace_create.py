@@ -114,8 +114,8 @@ def test_mace_constructs_and_trains_through_create():
 
 @pytest.mark.parametrize("l", [1, 2])
 def test_wigner_d_fit_is_fp64_regardless_of_rot_dtype(l):
-    """Regression for the BENCH_TPU ``Wigner D fit failed for l=1: err
-    0.00599`` failure: a float32 — or jax-array under default x64-off —
+    """Regression for ``Wigner D fit failed for l=1: err 0.00599``, as
+    a TPU run once failed: a float32 — or jax-array under default x64-off —
     rotation matrix must not drag the lstsq fit to fp32 (numpy defers
     ``v @ rot.T`` to ``jax.Array.__rmatmul__``), where the 1e-6 fp64
     verification tolerance is unreachable. The fit now coerces to

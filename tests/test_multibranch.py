@@ -145,7 +145,10 @@ def test_multibranch_gradient_semantics():
         )
 
 
-def test_multibranch_train_step_runs():
+@pytest.mark.parametrize("zero", [False, True], ids=["replicated", "zero"])
+def test_multibranch_train_step_runs(zero):
+    """``zero``: params and moments sharded over the data axis itself
+    (ZeRO/GSPMD), the layout of the multi-dataset job."""
     cfg = _cfg()
     model = create_model(cfg)
     mesh = make_mesh({"data": 8})
@@ -163,7 +166,12 @@ def test_multibranch_train_step_runs():
     state = create_train_state(params, tx, bs)
     from hydragnn_tpu.parallel.dp import replicate_state
 
-    state = replicate_state(state, mesh)
+    state = replicate_state(state, mesh, fsdp=zero, axis="data")
+    if zero:
+        assert any(
+            not p.sharding.is_fully_replicated
+            for p in jax.tree_util.tree_leaves(state.params)
+        )
     step = make_multibranch_train_step(model, tx, cfg, mesh, dpb)
     losses = []
     for epoch in range(8):

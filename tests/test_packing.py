@@ -190,13 +190,20 @@ def test_packed_loader_delivers_every_graph_once():
     ld = GraphLoader(samples, 16, shuffle=True, seed=5, packing=True)
     assert len(ld) == len(list(ld.epoch_plan(0)))
     seen = []
+    slots = real = 0
     for b in ld:
         gm = np.asarray(b.graph_mask)
         seen += [int(v) for v in np.asarray(b.y_graph)[gm, 0]]
+        slots += b.num_nodes + b.num_edges
+        real += int(np.asarray(b.node_mask).sum())
+        real += int(np.asarray(b.edge_mask).sum())
     assert sorted(seen) == list(range(120))
     st = ld.packing_stats()
     assert st is not None and 0.5 < st["node_fill"] <= 1.0
+    # the plan's ratio is the delivered batches': padded node+edge
+    # slots over what the masks mark real, never under 1
     assert st["pad_ratio"] >= 1.0
+    assert slots / real == st["pad_ratio"]
     # shapes come only from the fitted budgets
     keys = ld.planned_spec_keys()
     assert 1 <= len(keys) <= 2

@@ -224,8 +224,8 @@ def _run_dispatch_check():
 
 # ----------------------------------------------------------------------
 # Shape-keyed crossover dispatch (ISSUE 3: never pick the planned
-# kernel for oc20-class shapes where ROOFLINE_TPU.txt measures it
-# 0.48-0.77x vs XLA).
+# kernel for oc20-class shapes where the crossover table's measured
+# row has it slower than XLA).
 # ----------------------------------------------------------------------
 
 
@@ -1074,3 +1074,129 @@ def test_write_table_reloads_cache(tmp_path, monkeypatch):
     r = rows[0]
     assert r["bwd_wins"] is True and "bwd_measured" in r
     assert r["fused_wins"] is True and r["planned_wins"] is True
+
+
+# ----------------------------------------------------------------------
+# Contracts of the fused pipeline that are counts, not timings: modeled
+# traffic at the two classes of shape it was built for, and a train
+# loop that compiles once under forced fused dispatch.
+# ----------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "shape",
+    [(33792, 4224, 128, 128), (327680, 8192, 256, 256)],
+    ids=["qm9_b128", "oc20_b32"],
+)
+def test_modeled_traffic_fused_moves_fewer_bytes_per_flop(shape):
+    """One pass over aligned tiles must move strictly fewer HBM bytes
+    per model flop than reduce-then-matmul through the planned kernel:
+    the arithmetic intensity ``graftboard roofline`` attributes, from
+    sizes alone."""
+    from hydragnn_tpu.ops.pallas_segment import modeled_pipeline_traffic
+
+    e, n, fi, fo = shape
+    fused = modeled_pipeline_traffic(e, n, fi, fo, fused=True)
+    unfused = modeled_pipeline_traffic(e, n, fi, fo, fused=False)
+    assert fused["model_flops"] == unfused["model_flops"]
+    assert fused["bytes_per_flop"] < unfused["bytes_per_flop"]
+
+
+def test_forced_fused_dispatch_train_loop_compiles_once(monkeypatch):
+    """HYDRAGNN_TPU_SEGMENT_IMPL=pallas_fused sends the forward AND the
+    pullback of a bf16 train step through the planned kernels. The plan
+    arrays are batch data (in the vjp's residuals too): the warm epoch
+    compiles one step per packed budget and three more epochs replay
+    them. A compile after warm-up means a plan array was traced as a
+    constant."""
+    import hydragnn_tpu.ops.pallas_segment as ps
+    from hydragnn_tpu.config import update_config
+    from hydragnn_tpu.data.graph import GraphSample
+    from hydragnn_tpu.data.loader import GraphLoader
+    from hydragnn_tpu.models.create import create_model_config, init_params
+    from hydragnn_tpu.ops.neighbors import radius_graph
+    from hydragnn_tpu.train.loop import _run_epoch, make_train_step
+    from hydragnn_tpu.train.optimizer import select_optimizer
+    from hydragnn_tpu.train.state import create_train_state
+    from hydragnn_tpu.utils import telemetry
+
+    rng = np.random.default_rng(0)
+    samples = []
+    for _ in range(64):
+        n = int(rng.integers(9, 30))
+        pos = rng.uniform(0, 2.2 * n ** (1 / 3), size=(n, 3))
+        samples.append(
+            GraphSample(
+                x=rng.integers(0, 5, size=(n, 1)).astype(np.float32),
+                pos=pos.astype(np.float32),
+                edge_index=radius_graph(pos, 4.0, max_neighbours=32),
+                y_graph=np.array([rng.normal()], np.float32),
+            )
+        )
+    head = {
+        "num_sharedlayers": 2,
+        "dim_sharedlayers": 16,
+        "num_headlayers": 2,
+        "dim_headlayers": [16, 16],
+    }
+    config = {
+        "NeuralNetwork": {
+            "Architecture": {
+                "mpnn_type": "SchNet",
+                "radius": 4.0,
+                "max_neighbours": 32,
+                "num_gaussians": 8,
+                "num_filters": 16,
+                "hidden_dim": 16,
+                "num_conv_layers": 2,
+                "output_heads": {"graph": head},
+                "task_weights": [1.0],
+            },
+            "Variables_of_interest": {
+                "input_node_features": [0],
+                "output_names": ["energy"],
+                "output_index": [0],
+                "type": ["graph"],
+                "output_dim": [1],
+            },
+            "Training": {
+                "batch_size": 8,
+                "Optimizer": {"type": "AdamW", "learning_rate": 1e-3},
+            },
+        }
+    }
+    cfgd = update_config(config, samples)
+    monkeypatch.setenv("HYDRAGNN_TPU_SEGMENT_IMPL", "pallas_fused")
+    calls = []
+    for name in ("edge_pipeline_planned", "edge_pipeline_bwd_planned"):
+        real = getattr(ps, name)
+
+        def counting(*a, _real=real, _name=name, **kw):
+            calls.append(_name)
+            return _real(*a, **kw)
+
+        monkeypatch.setattr(ps, name, counting)
+    loader = GraphLoader(
+        samples, 8, shuffle=True, seed=0, packing=True,
+        with_segment_plan=True,
+    )
+    first = next(iter(loader))
+    assert first.seg_window is not None, "loader attached no plan"
+    model, cfg = create_model_config(cfgd)
+    params, bs = init_params(model, first)
+    tx = select_optimizer(cfgd["NeuralNetwork"]["Training"])
+    step = make_train_step(
+        model, tx, cfg, compute_dtype=jnp.bfloat16, donate=False
+    )
+    state = create_train_state(params, tx, bs)
+    obs = telemetry.install_observer()
+    try:
+        for epoch in range(4):
+            obs.set_phase(epoch)
+            loader.set_epoch(epoch)
+            state, _, _ = _run_epoch(step, state, loader, train=True)
+        leaks = list(obs.post_warmup)
+    finally:
+        obs.close()
+    assert {"edge_pipeline_planned", "edge_pipeline_bwd_planned"} <= set(calls)
+    assert not leaks, leaks

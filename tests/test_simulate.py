@@ -308,6 +308,7 @@ def test_rollout_telemetry_rows(potential, tmp_path):
     """Every macro emits a ``rollout`` row (docs/OBSERVABILITY.md);
     the rows carry the documented fields and graftboard aggregates
     them into the simulation section."""
+    from hydragnn_tpu.simulate.engine import macro_plan
     from hydragnn_tpu.utils import telemetry
 
     stream_path = str(tmp_path / "telemetry.jsonl")
@@ -325,6 +326,11 @@ def test_rollout_telemetry_rows(potential, tmp_path):
     ]
     rollout = [r for r in rows if r.get("t") == "rollout"]
     assert len(rollout) == 3  # 24 steps / K=8
+    # a clean rollout dispatches exactly the chunking macro_plan names:
+    # full K macros and one shorter tail, 16x fewer dispatches at K=16
+    assert macro_plan(24, 8) == [r["committed"] for r in rollout]
+    assert macro_plan(11, 4) == [4, 4, 3]
+    assert len(macro_plan(128, 1)) == 16 * len(macro_plan(128, 16))
     required = {
         "macro",
         "step",
@@ -345,6 +351,9 @@ def test_rollout_telemetry_rows(potential, tmp_path):
         assert required <= set(r), sorted(required - set(r))
     assert rollout[-1]["step"] == 24
     assert all(r["overflow"] == 0 and not r["nonfinite"] for r in rollout)
+    assert all(
+        r["dispatch_ms"] > 0.0 and np.isfinite(r["energy"]) for r in rollout
+    )
 
     import sys
 

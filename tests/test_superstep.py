@@ -170,6 +170,29 @@ def test_superstep_groups_none_spec_stays_single():
     assert groups[1][0][1] is None
 
 
+def test_packed_epoch_plan_folds_4x_at_k8():
+    """Packing collapses an epoch of varied molecules to a couple of
+    budget shapes in spec-major order, so K=8 cuts the Python
+    dispatches of the epoch at least 4x; the bucket ladder over the
+    same shuffle has no runs to fold."""
+    from hydragnn_tpu.data.loader import GraphLoader
+    from hydragnn_tpu.data.padschedule import superstep_groups
+
+    samples = _mols(512, 9, 30, seed=0)
+    plan = list(
+        GraphLoader(
+            samples, 16, shuffle=True, seed=0, packing=True
+        ).epoch_plan(0)
+    )
+    assert len(plan) >= 4 * len(superstep_groups(plan, 8))
+    ladder = list(
+        GraphLoader(
+            samples, 16, shuffle=True, seed=0, fixed_pad="auto"
+        ).epoch_plan(0)
+    )
+    assert len(ladder) < 2 * len(superstep_groups(ladder, 8))
+
+
 def test_auto_superstep_k_floor_cap_and_fragmentation():
     from hydragnn_tpu.data.padschedule import (
         auto_superstep_k,

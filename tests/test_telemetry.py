@@ -2,8 +2,8 @@
 bounded non-blocking stream writer (incl. fault posture via
 utils/faults.py), the step clock across every feed/scheme combination
 (serial, pipeline, superstep, dp), per-epoch rollups bit-equal to the
-loop's History, live MFU consistent with bench.py's flop arithmetic to
-1e-9 relative, the compile/retrace observer, graftboard parsing (incl.
+loop's History, live MFU consistent with utils/flops.py's arithmetic
+to 1e-9 relative, the compile/retrace observer, graftboard parsing (incl.
 the truncated-tail tolerance), and the RegionTimer.reset regression.
 
 Training runs use a uniform-size dataset so the packed plan is a
@@ -300,8 +300,8 @@ def _assert_losses_bit_equal(rows, hist):
 
 def _assert_mfu_consistent(rows, cfg):
     """The acceptance contract: per-spec MFU in the stream reproduces
-    bench.py's flop arithmetic (the SAME utils/flops function over the
-    row's own emitted fields) to 1e-9 relative."""
+    utils/flops.py's arithmetic (the SAME function over the row's own
+    emitted fields) to 1e-9 relative."""
     from hydragnn_tpu.utils.flops import model_flops_per_graph
 
     mfu_rows = [
@@ -317,6 +317,43 @@ def _assert_mfu_consistent(rows, cfg):
             expect,
         )
         assert r["model_flops_per_graph"] == mf
+
+
+@pytest.mark.parametrize(
+    "F,G,L,H,expect",
+    [
+        # hidden_dim == num_filters: the count from before lin1/lin2
+        # were told apart (both F x F), to the last digit
+        (
+            128.0, 50.0, 6.0, 128.0,
+            3.0 * (
+                6.0 * (
+                    2 * 100 * (50.0 * 128.0 + 128.0 * 128.0)
+                    + 2 * 10 * (2 * 128.0 * 128.0)
+                    + 2 * 100 * 128.0
+                )
+                + 2 * 10 * 128.0 * 128.0
+                + 6 * 128.0 * 128.0
+            ),
+        ),
+        # schnet_oc20's widths, hidden 1024 / 256 filters, by hand for
+        # n=10, e=100. A layer: filter MLP 2*100*(200*256 + 256*256)
+        # = 23,347,200; lin1 (H x F) and lin2 (F x H) 2*10*(262,144 +
+        # 262,144) = 10,485,760; product and sum 2*100*256 = 51,200;
+        # 33,884,160 a layer, 169,420,800 for 5. Embed 2*10*1024^2 =
+        # 20,971,520, heads 6*1024^2 = 6,291,456: 196,683,776 forward,
+        # times 3.
+        (256.0, 200.0, 5.0, 1024.0, 590051328.0),
+    ],
+)
+def test_schnet_flops_tells_lin1_and_lin2_by_hidden_and_filters(
+    F, G, L, H, expect
+):
+    """``lin1`` is hidden x filters and ``lin2`` filters x hidden: the
+    count behind telemetry's MFU column where they differ."""
+    from hydragnn_tpu.utils.flops import schnet_flops
+
+    assert schnet_flops(10, 100, F, G, L, H) == expect
 
 
 def test_serial_feed_stream(tmp_path):
@@ -589,8 +626,8 @@ def test_header_self_description(tmp_path):
 
 
 def test_compiled_cost_stats_matches_raw_cost_analysis():
-    """The shared parse (bench dedupe satellite): flops/bytes equal the
-    raw Compiled.cost_analysis values bench.py used to parse inline."""
+    """The parse behind the ``executable`` rows: flops/bytes equal
+    the raw Compiled.cost_analysis values."""
     from hydragnn_tpu.utils.flops import (
         compiled_cost_stats,
         compiled_memory_stats,
@@ -627,7 +664,7 @@ def test_resolve_peak_bandwidth_anchor_and_device():
 
     bw, basis = resolve_peak_bandwidth("TPU v4")
     assert bw == PEAK_HBM_BYTES_PER_SEC["TPU v4"] and basis == "device"
-    # explicit CPU run -> ROOFLINE_TPU.txt anchor (its measured header)
+    # explicit CPU run -> the anchor chip's row of the table
     bw, basis = resolve_peak_bandwidth("cpu")
     assert basis == "roofline_anchor" and bw == 819.0e9
 

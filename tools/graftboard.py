@@ -521,7 +521,7 @@ def render_roofline(rl: dict) -> str:
     )
     if rl["what_if"]:
         out.append(
-            "NOTE: peak basis is the ROOFLINE_TPU.txt anchor chip — "
+            "NOTE: peak basis is the anchor chip of a CPU run — "
             "utilization/ceiling columns are a WHAT-IF on that chip, "
             "not a measurement of this host."
         )

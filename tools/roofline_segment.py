@@ -43,8 +43,8 @@ measured on a TPU get ``planned_measured``/``fused_measured``/
 ``bwd_measured`` = true and become dispatch verdicts; rows produced
 off-TPU are labeled WHAT-IF (``*_measured`` = false) and are NEVER
 dispatched on (graftboard's no-fabrication rule) — the checked-in
-seed therefore stays the CPU/CI fallback with only the
-ROOFLINE_TPU.txt-measured planned anchors active. After a write the
+seed therefore stays the CPU/CI fallback with only its two measured
+planned anchors active. After a write the
 in-process table cache is invalidated (reload_crossover_table), so a
 refreshed table takes effect without a process restart.
 """
@@ -72,9 +72,9 @@ PEAK_BW = {
 
 # Shape grid: the packed-budget classes x feature width. num_filters
 # for zinc/qm9-class models is 64-128; oc20-class runs wider. The
-# anchors (qm9_b128_f128, oc20_b32_f256) coincide with the
-# ROOFLINE_TPU.txt round-3 measured shapes so the historical planned
-# verdicts stay attached to real rows.
+# anchors (qm9_b128_f128, oc20_b32_f256) coincide with the seed
+# table's two measured shapes so the historical planned verdicts stay
+# attached to real rows.
 SHAPES = {
     # name: (num_nodes, num_edges, feature_dim)
     "zinc_b64_f64": (1408, 3456, 64),

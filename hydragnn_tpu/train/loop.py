@@ -1179,14 +1179,12 @@ def train_validate_test(
     tb_writer = None
     log_name = config.get("_log_name")
     if log_name and jax.process_index() == 0:
-        # the import drags torch and tensorflow in (about 20 s of the
-        # v5e cells' set-up, PERF.md): a set-up phase of its own
         with telemetry.setup_phase("writers"):
             try:
-                from torch.utils.tensorboard import SummaryWriter
+                from hydragnn_tpu.utils.scalars import ScalarsWriter
 
-                tb_writer = SummaryWriter(log_dir=f"logs/{log_name}/tb")
-            except Exception:
+                tb_writer = ScalarsWriter(f"logs/{log_name}/tb")
+            except ImportError:  # no tensorboard installed: no scalars
                 tb_writer = None
 
     # Plateau scheduler: reference hardcodes factor=0.5/patience=5/
@@ -1477,6 +1475,7 @@ def train_validate_test(
                 tb_writer.add_scalar("lr", new_lr, epoch)
                 for ti, tv in enumerate(np.asarray(train_tasks).reshape(-1)):
                     tb_writer.add_scalar(f"task{ti}/train", float(tv), epoch)
+                tb_writer.flush()
 
             print_distributed(
                 verbosity,

@@ -161,6 +161,7 @@ class GraphLoader:
         self._skip_next = 0
         self._auto_selected = False
         self._seen_specs: set = set()
+        self._triplet_size_cache: Optional[np.ndarray] = None
         self.spec_schedule = spec_schedule
         if spec_schedule is not None:
             if with_triplets:
@@ -343,6 +344,27 @@ class GraphLoader:
             )
         return batch_size_rows(nodes, edges, self._epoch_batches(epoch))
 
+    def _triplet_sizes(self) -> np.ndarray:
+        """Per-sample angular triplet counts (``count_triplets``), one
+        scan, cached: the worst-case spec and the step rows share it."""
+        if self._triplet_size_cache is None:
+            from hydragnn_tpu.data.graph import count_triplets
+
+            self._triplet_size_cache = np.array(
+                [count_triplets(s) for s in self.dataset], dtype=np.int64
+            )
+        return self._triplet_size_cache
+
+    def epoch_triplet_rows(self, epoch: int) -> np.ndarray:
+        """[n_batches] real triplets per batch for one epoch, in the
+        order of ``epoch_size_rows`` (a triplet-bearing loader keeps
+        fixed-size batches: packing budgets do not cover triplets)."""
+        t = self._triplet_sizes()
+        return np.asarray(
+            [int(t[idx].sum()) for idx in self._epoch_batches(epoch)],
+            np.int64,
+        )
+
     def planned_spec_keys(self, epochs: int = 2) -> set:
         """Distinct bucketed-PadSpec keys (nodes, edges, graphs) the
         first ``epochs`` epochs would produce under ``fixed_pad=False``
@@ -384,12 +406,10 @@ class GraphLoader:
         )
         if not self.with_triplets:
             return spec
-        from hydragnn_tpu.data.graph import bucket_size, count_triplets
+        from hydragnn_tpu.data.graph import bucket_size
 
-        t_sizes = sorted(
-            (count_triplets(s) for s in self.dataset), reverse=True
-        )
-        t = bucket_size(max(sum(t_sizes[: self.batch_size]), 1))
+        t_sizes = np.sort(self._triplet_sizes())[::-1]
+        t = bucket_size(max(int(t_sizes[: self.batch_size].sum()), 1))
         return PadSpec(
             num_nodes=spec.num_nodes,
             num_edges=spec.num_edges,

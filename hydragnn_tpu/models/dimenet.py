@@ -22,6 +22,7 @@ from hydragnn_tpu.data.graph import GraphBatch
 from hydragnn_tpu.models.spec import ModelConfig
 from hydragnn_tpu.ops import edge_vectors_and_lengths, segment_sum
 from hydragnn_tpu.ops.sbf import bessel_basis_envelope, spherical_basis
+from hydragnn_tpu.utils import tracer as tr
 
 ACT = jax.nn.silu
 
@@ -87,14 +88,17 @@ class InteractionPPBlock(nn.Module):
 
         x_kj = ACT(nn.Dense(I, name="lin_down")(x_kj))
 
-        sbf_p = nn.Dense(self.basis_emb_size, use_bias=False, name="lin_sbf1")(sbf)
-        sbf_p = nn.Dense(I, use_bias=False, name="lin_sbf2")(sbf_p)
-        # Per-triplet: message of edge k->j modulated by angular basis,
-        # summed into edge j->i.
-        trip = x_kj[batch.t_kj] * sbf_p
-        x_kj = segment_sum(
-            trip, batch.t_ji, m.shape[0], mask=batch.triplet_mask
-        )
+        # The triplet exchange, timed under one scope: basis projection,
+        # gather of the k->j messages, product, and their sum into j->i.
+        with tr.scope("triplet"):
+            sbf_p = nn.Dense(
+                self.basis_emb_size, use_bias=False, name="lin_sbf1"
+            )(sbf)
+            sbf_p = nn.Dense(I, use_bias=False, name="lin_sbf2")(sbf_p)
+            trip = x_kj[batch.t_kj] * sbf_p
+            x_kj = segment_sum(
+                trip, batch.t_ji, m.shape[0], mask=batch.triplet_mask
+            )
         x_kj = ACT(nn.Dense(H, name="lin_up")(x_kj))
 
         h = x_ji + x_kj
@@ -203,27 +207,27 @@ class DIMEStack(nn.Module):
         vec, dist = edge_vectors_and_lengths(
             batch.pos, batch.senders, batch.receivers, batch.edge_shifts
         )
-        # Angle at node i between directions i->j and i->k, composed from
-        # edge vectors so PBC shifts are respected (reference
-        # DIMEStack._embedding, hydragnn/models/DIMEStack.py:180-186).
-        v_ji = vec[batch.t_ji]  # pos_j - pos_i
-        v_ki = vec[batch.t_kj] + v_ji  # pos_k - pos_i
-        a = jnp.sum(v_ji * v_ki, axis=-1)
-        b = jnp.linalg.norm(jnp.cross(v_ji, v_ki), axis=-1)
-        angle = jnp.arctan2(b, a)
-
         rbf = bessel_basis_envelope(
             dist, cfg.radius, s["num_radial"], s["envelope_exponent"]
         )
-        sbf = spherical_basis(
-            dist,
-            angle,
-            batch.t_kj,
-            cutoff=cfg.radius,
-            num_spherical=s["num_spherical"],
-            num_radial=s["num_radial"],
-            envelope_exponent=s["envelope_exponent"],
-        )
+        with tr.scope("triplet_basis"):
+            # Angle at node i between directions i->j and i->k, composed
+            # from edge vectors so PBC shifts are respected (reference
+            # DIMEStack._embedding, hydragnn/models/DIMEStack.py:180-186).
+            v_ji = vec[batch.t_ji]  # pos_j - pos_i
+            v_ki = vec[batch.t_kj] + v_ji  # pos_k - pos_i
+            a = jnp.sum(v_ji * v_ki, axis=-1)
+            b = jnp.linalg.norm(jnp.cross(v_ji, v_ki), axis=-1)
+            angle = jnp.arctan2(b, a)
+            sbf = spherical_basis(
+                dist,
+                angle,
+                batch.t_kj,
+                cutoff=cfg.radius,
+                num_spherical=s["num_spherical"],
+                num_radial=s["num_radial"],
+                envelope_exponent=s["envelope_exponent"],
+            )
         return batch.x, batch.pos, {"rbf": rbf, "sbf": sbf}
 
     def conv(

@@ -126,12 +126,18 @@ def _radial_cheb_coeffs(
 
 def chebyshev_eval(t: jax.Array, coeffs: jax.Array) -> jax.Array:
     """Evaluate Chebyshev series sum_k c_k T_k(t) for a coefficient matrix
-    [K, F]: one cos(k*arccos t) feature map and a matmul."""
+    [K, F]: one cos(k*arccos t) feature map and a matmul.
+
+    The matmul is pinned to ``Precision.HIGHEST``: at the default
+    precision a TPU rounds both operands to bfloat16, and the basis then
+    lies further from its float64 values than its float32 evaluation
+    does (PERF.md, dimenet_pp_qm9). It is ``[E, K] x [K, F]``, a few
+    MFLOP a step."""
     K = coeffs.shape[0]
     tc = jnp.clip(t, -1.0, 1.0)
     theta = jnp.arccos(tc)
     feats = jnp.cos(theta[..., None] * jnp.arange(K, dtype=t.dtype))
-    return feats @ coeffs
+    return jnp.matmul(feats, coeffs, precision=jax.lax.Precision.HIGHEST)
 
 
 def legendre_pl(c: jax.Array, l_max: int) -> jax.Array:

@@ -1032,6 +1032,16 @@ def _spec_of(batch) -> tuple:
     return (f"n{n}_e{e}_g{g}", n, e, g)
 
 
+def _triplets_pad(batch) -> Optional[int]:
+    """Padded triplet slots of one step (the last axis of ``t_kj``), or
+    None for a batch without triplets."""
+    from hydragnn_tpu.data.graph import MacroBatch
+
+    b = batch.batch if isinstance(batch, MacroBatch) else batch
+    t_kj = getattr(b, "t_kj", None)
+    return None if t_kj is None else int(t_kj.shape[-1])
+
+
 class StepClock:
     """Per-epoch step clock — built by ``epoch_clock`` and driven by
     ``train/loop._run_epoch``. Collects one row per DISPATCH (a
@@ -1051,6 +1061,7 @@ class StepClock:
         d: int = 1,
         step0: int = 0,
         size_rows=None,
+        triplet_rows=None,
         model_cfg=None,
         lr: Optional[float] = None,
     ) -> None:
@@ -1066,6 +1077,7 @@ class StepClock:
         self._rows: List[dict] = []
         self._refs: List[Any] = []
         self._size_rows = size_rows  # [n_plan_steps, 3] or None
+        self._triplet_rows = triplet_rows  # [n_plan_steps] or None
         self._size_cursor = int(step0) * self.d
         self._prev_end: Optional[float] = None
         self._t_first: Optional[float] = None
@@ -1157,6 +1169,13 @@ class StepClock:
             row["nodes"] = int(sl[:, 0].sum()) - take
             row["edges"] = int(sl[:, 1].sum())
             row["graphs_plan"] = int(sl[:, 2].sum()) - take
+        trips = self._triplet_rows
+        t_pad = _triplets_pad(batch) if trips is not None else None
+        if t_pad is not None and self._size_cursor + take <= len(trips):
+            row["triplets"] = int(
+                trips[self._size_cursor : self._size_cursor + take].sum()
+            )
+            row["triplets_pad"] = t_pad
         self._size_cursor += take
         self._n_records += 1
         # Liveness counters for the heartbeat rows: a process whose
@@ -1447,12 +1466,14 @@ def epoch_clock(loader, region: str, step0: int = 0) -> Optional[StepClock]:
     plan_epoch = int(getattr(base, "_epoch", 0) or 0)
     ctx = _CONTEXT
     epoch = int(ctx.get("epoch", plan_epoch)) if "epoch" in ctx else plan_epoch
-    size_rows = None
+    size_rows = triplet_rows = None
     if base is not None:
         try:
             size_rows = base.epoch_size_rows(plan_epoch)
         except Exception:
             size_rows = None  # lazy containers without size metadata
+        if size_rows is not None and getattr(base, "with_triplets", False):
+            triplet_rows = base.epoch_triplet_rows(plan_epoch)
     return StepClock(
         stream,
         region=region,
@@ -1462,6 +1483,7 @@ def epoch_clock(loader, region: str, step0: int = 0) -> Optional[StepClock]:
         d=d,
         step0=step0,
         size_rows=size_rows,
+        triplet_rows=triplet_rows,
         model_cfg=ctx.get("model_cfg"),
         lr=ctx.get("lr"),
     )

@@ -242,6 +242,8 @@ SCOPES = (
     "forces",
     "optimizer",
     "guard",
+    "triplet",
+    "triplet_basis",
 )
 
 

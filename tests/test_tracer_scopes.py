@@ -469,3 +469,42 @@ def test_step_clock_refs_survive_a_donating_superstep(tmp_path):
     steps = [r for r in _rows(str(tmp_path / "t.jsonl")) if r["t"] == "step"]
     assert [r["k"] for r in steps] == [1, 2]
     assert steps[0]["graphs"] == 4
+
+
+def test_dimenet_triplet_scopes_forward_and_transpose():
+    """DimeNet++'s triplet exchange reads as ``triplet`` in every block,
+    forward and under ``transpose(``, with the reduce nested as
+    ``triplet/segment/sum``; the angles and the spherical basis read as
+    ``triplet_basis``. The benchmark's triplet readers key on them."""
+    from hydragnn_tpu.config import update_config
+    from hydragnn_tpu.data.loader import GraphLoader
+    from hydragnn_tpu.models.create import create_model_config, init_params
+    from hydragnn_tpu.train import loop
+    from hydragnn_tpu.train.optimizer import select_optimizer
+    from hydragnn_tpu.train.state import create_train_state
+
+    samples = _mols(8, seed=4)
+    config = _config()
+    config["NeuralNetwork"]["Architecture"].update(
+        mpnn_type="DimeNet", num_radial=3, num_spherical=2,
+        envelope_exponent=5, int_emb_size=4, basis_emb_size=2,
+        out_emb_size=8,
+    )
+    cfgd = update_config(config, samples)
+    model, cfg = create_model_config(cfgd)
+    batch = next(iter(GraphLoader(samples, 4, with_triplets=True)))
+    params, bs = init_params(model, batch)
+    tx = select_optimizer(cfgd["NeuralNetwork"]["Training"])
+    state = create_train_state(params, tx, bs)
+    text = loop.make_train_step(model, tx, cfg, donate=False).lower(
+        state, batch
+    ).as_text(debug_info=True)
+    paths = _paths(text)
+    trip = _under(paths, "triplet")
+    for i in range(cfg.num_conv_layers):
+        block = [p for p in trip if f"/inter_{i}/triplet/" in p]
+        assert any("transpose(" not in p for p in block), i
+        assert any("transpose(" in p for p in block), i
+        assert any("/triplet/segment/sum/" in p for p in block), i
+    basis = _under(paths, "triplet_basis")
+    assert basis and all("/inter_" not in p for p in basis)

@@ -93,6 +93,12 @@ class GraphBatch:
     # tells XLA its scatter's indices are sorted (ops/segment.py).
     # Whoever writes other receivers into a batch must drop it.
     receivers_sorted: bool = struct.field(pytree_node=False, default=False)
+    # Collation's promise that ``t_ji`` is nondecreasing, padding triplets
+    # included (they point at the last edge slot, ``fill_triplets``): the
+    # triplet reduce then tells XLA its scatter's indices are sorted
+    # (ops/segment.py). Whoever writes other triplets into a batch must
+    # drop it.
+    triplets_sorted: bool = struct.field(pytree_node=False, default=False)
 
     # ------------------------------------------------------------------
     @property
@@ -478,7 +484,12 @@ def fill_triplets(t_kj, t_ji, triplet_mask, senders, receivers, e_real, n_real):
     ``np.empty`` — every slot is written). Padding triplets reference
     the last edge slot (a self-loop at the padding node) and are masked
     out of all reductions. Shared by ``collate`` and the packed
-    collators."""
+    collators.
+
+    Keeps ``t_ji`` nondecreasing over all ``T`` slots, which the batch
+    promises as ``triplets_sorted``: ``build_triplets`` enumerates the
+    j->i edges in index order, and the padding's ``E - 1`` is at least
+    every real edge index."""
     T = int(t_kj.shape[0])
     E = int(senders.shape[0])
     kj, ji = build_triplets(senders[:e_real], receivers[:e_real], n_real)
@@ -771,6 +782,7 @@ def collate(
         t_kj=t_kj,
         t_ji=t_ji,
         triplet_mask=triplet_mask,
+        triplets_sorted=t_ji is not None,
         **edge_plans,
     )
     if as_numpy:

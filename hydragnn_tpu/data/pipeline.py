@@ -202,7 +202,8 @@ def _plans_into_buffers(
             t_kj, t_ji, triplet_mask, senders, receivers, e_real, n_real
         )
     return dict(
-        edge_plans, t_kj=t_kj, t_ji=t_ji, triplet_mask=triplet_mask
+        edge_plans, t_kj=t_kj, t_ji=t_ji, triplet_mask=triplet_mask,
+        triplets_sorted=t_ji is not None,
     )
 
 
@@ -493,7 +494,8 @@ def _stack_group(batches: List[GraphBatch], out: Dict[str, np.ndarray]) -> Macro
     for f in _dc.fields(GraphBatch):
         xs = [getattr(b, f.name) for b in batches]
         if not f.metadata.get("pytree_node", True):
-            # static metadata (receivers_sorted): one value for the group
+            # static metadata (receivers_sorted, triplets_sorted): one
+            # value for the group
             if any(x != xs[0] for x in xs):
                 raise ValueError(
                     f"superstep group mixes values of `{f.name}`"

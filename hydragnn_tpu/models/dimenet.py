@@ -20,7 +20,8 @@ from flax import linen as nn
 
 from hydragnn_tpu.data.graph import GraphBatch
 from hydragnn_tpu.models.spec import ModelConfig
-from hydragnn_tpu.ops import edge_vectors_and_lengths, segment_sum
+from hydragnn_tpu.ops import edge_vectors_and_lengths
+from hydragnn_tpu.ops.segment import _sum_at_receivers, sum_over_triplets
 from hydragnn_tpu.ops.sbf import bessel_basis_envelope, spherical_basis
 from hydragnn_tpu.utils import tracer as tr
 
@@ -95,10 +96,7 @@ class InteractionPPBlock(nn.Module):
                 self.basis_emb_size, use_bias=False, name="lin_sbf1"
             )(sbf)
             sbf_p = nn.Dense(I, use_bias=False, name="lin_sbf2")(sbf_p)
-            trip = x_kj[batch.t_kj] * sbf_p
-            x_kj = segment_sum(
-                trip, batch.t_ji, m.shape[0], mask=batch.triplet_mask
-            )
+            x_kj = sum_over_triplets(x_kj[batch.t_kj] * sbf_p, batch)
         x_kj = ACT(nn.Dense(H, name="lin_up")(x_kj))
 
         h = x_ji + x_kj
@@ -123,9 +121,7 @@ class OutputPPBlock(nn.Module):
         self, m: jax.Array, rbf: jax.Array, batch: GraphBatch
     ) -> jax.Array:
         g = nn.Dense(m.shape[-1], use_bias=False, name="lin_rbf")(rbf)
-        node = segment_sum(
-            g * m, batch.receivers, batch.num_nodes, mask=batch.edge_mask
-        )
+        node = _sum_at_receivers(g * m, batch)
         node = nn.Dense(self.out_emb_size, use_bias=False, name="lin_up")(node)
         for i in range(self.num_layers):
             node = ACT(nn.Dense(self.out_emb_size, name=f"lin_{i}")(node))

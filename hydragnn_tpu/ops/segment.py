@@ -10,7 +10,8 @@ another, at 38-56 GB/s (PERF.md sections 5 and 6). Told that the
 indices are sorted it skips the sort and reads the rows in place: the
 same additions in the same order in two thirds of the time, so the
 receiver aggregation tells it where collation has sorted the edges
-(``_sum_at_receivers``).
+(``_sum_at_receivers``), and DimeNet's triplet reduce where collation
+has built the triplets in ``t_ji`` order (``sum_over_triplets``).
 """
 
 from __future__ import annotations
@@ -258,19 +259,26 @@ def _plan_dispatch(
     )
 
 
-# Receiver-aggregation call sites by the scatter they lowered to, counted
-# while a program is traced (the choice compiles away): ``dispatch_counter``
-# around a jitted function's body writes them as one telemetry row.
-_DISPATCH = {"sorted_scatter": 0, "scatter": 0}
+# Receiver-aggregation and triplet-reduce call sites by the scatter they
+# lowered to, counted while a program is traced (the choice compiles
+# away): ``dispatch_counter`` around a jitted function's body writes them
+# as one telemetry row.
+_DISPATCH = {
+    "sorted_scatter": 0,
+    "scatter": 0,
+    "triplet_sorted_scatter": 0,
+    "triplet_scatter": 0,
+}
 
 
 @contextlib.contextmanager
 def dispatch_counter(program: str):
-    """Trace-time: count the ``aggregate_receivers*`` call sites inside
-    that reach XLA's scatter by whether they could promise it sorted
-    indices, and write one row ``{"t": "setup", "phase":
-    "segment_dispatch", "program": ..., "sorted_scatter": n, "scatter":
-    m}`` to the live telemetry stream."""
+    """Trace-time: count the receiver sums (``_sum_at_receivers``) and
+    the triplet reduces (``sum_over_triplets``) inside by whether they
+    could promise XLA's scatter sorted indices, and write one row
+    ``{"t": "setup", "phase": "segment_dispatch", "program": ...,
+    "sorted_scatter": n, "scatter": m, "triplet_sorted_scatter": p,
+    "triplet_scatter": q}`` to the live telemetry stream."""
     from hydragnn_tpu.utils import telemetry
 
     before = dict(_DISPATCH)
@@ -302,6 +310,23 @@ def _sum_at_receivers(msg: jax.Array, batch) -> jax.Array:
     _DISPATCH["sorted_scatter" if promised else "scatter"] += 1
     return segment_sum(
         msg, batch.receivers, batch.num_nodes, mask=batch.edge_mask,
+        indices_are_sorted=promised,
+    )
+
+
+def sum_over_triplets(trip: jax.Array, batch) -> jax.Array:
+    """DimeNet's triplet reduce ``[T, F] -> [E, F]``: each triplet's row
+    summed into its j->i edge ``t_ji``, padding triplets masked, by XLA's
+    scatter-add told that the indices are sorted where the batch
+    promises it (``GraphBatch.triplets_sorted``, collation's word). The
+    same additions in the same order as the plain scatter, which sorts
+    the ``T`` indices first and then reads the rows through the
+    permutation."""
+    # a batch-like that says nothing (a hand-made namespace) promises nothing
+    promised = bool(getattr(batch, "triplets_sorted", False))
+    _DISPATCH["triplet_sorted_scatter" if promised else "triplet_scatter"] += 1
+    return segment_sum(
+        trip, batch.t_ji, batch.num_edges, mask=batch.triplet_mask,
         indices_are_sorted=promised,
     )
 

@@ -165,6 +165,7 @@ def test_sorted_scatter_keeps_the_scope_and_reports_its_dispatch(
     assert (row["sorted_scatter"], row["scatter"]) == (
         (sites, 0) if promised else (0, sites)
     )
+    assert (row["triplet_sorted_scatter"], row["triplet_scatter"]) == (0, 0)
     # each layer's forward reduce is the one scatter that carries the
     # promise (the sender gather's transpose is a scatter too, unsorted),
     # and it sits where the parent's did

@@ -21,6 +21,7 @@ the losses.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, Dict, List, Optional, Tuple, Type
 
 import jax
@@ -485,10 +486,17 @@ class MultiHeadGraphModel(nn.Module):
     def __call__(
         self, batch: GraphBatch, *, train: bool = False
     ) -> List[jax.Array]:
-        cfg = self.cfg
-        if self.per_layer_readouts:
-            return self._forward_per_layer_readouts(batch, train=train)
-        node_repr, _ = self.encode(batch, train=train)
-        return self.decoder(
-            node_repr, self._pool(node_repr, batch), batch, train=train
-        )
+        # A stated precision reaches every product of the model, and their
+        # transposes inherit it.
+        precision = self.cfg.matmul_precision
+        with (
+            jax.default_matmul_precision(precision)
+            if precision
+            else contextlib.nullcontext()
+        ):
+            if self.per_layer_readouts:
+                return self._forward_per_layer_readouts(batch, train=train)
+            node_repr, _ = self.encode(batch, train=train)
+            return self.decoder(
+                node_repr, self._pool(node_repr, batch), batch, train=train
+            )

@@ -11,6 +11,10 @@ TPU-native reimplementations of:
     MLP, split into three gates (vector-state gate, edge-direction
     gate, scalar message); update = U/V linear maps on the vector
     channel with norm/inner-product mixing (PAINNStack.py:275-330).
+    The edge-direction gate multiplies r_ij / d_ij^2, where the paper
+    (Schuett et al., arXiv:2102.03150, eq. 8) has the unit vector
+    r_ij / d_ij: HydraGNN divides the normalised displacement by the
+    distance once more (PAINNStack.py:255-258), and this stack keeps it.
   - PNAEqStack (hydragnn/models/PNAEqStack.py:41-538): the PaiNN layout
     with PNA multi-aggregator/degree-scaler aggregation of the scalar
     message channel (aggregators mean/min/max/std x scalers identity/
@@ -30,7 +34,7 @@ import numpy as np
 from flax import linen as nn
 
 from hydragnn_tpu.data.graph import GraphBatch
-from hydragnn_tpu.models.layers import MLP
+from hydragnn_tpu.models.layers import MLP, species_onehot
 from hydragnn_tpu.models.pna import _deg_stats, pna_scaled_aggregate
 from hydragnn_tpu.models.spec import ModelConfig
 from hydragnn_tpu.ops import (
@@ -41,6 +45,7 @@ from hydragnn_tpu.ops import (
     sinc_basis,
 )
 from hydragnn_tpu.ops.segment import aggregate_receivers
+from hydragnn_tpu.utils import tracer as tr
 
 
 # ----------------------------------------------------------------------
@@ -161,37 +166,38 @@ class PainnMessage(nn.Module):
         snd, rcv = batch.senders, batch.receivers
         F = self.node_size
 
-        rbf = sinc_basis(dist, self.cutoff, self.num_radial)
-        filt = nn.Dense(3 * F, name="filter_layer")(rbf)
-        filt = filt * cosine_cutoff(dist, self.cutoff)[:, None]
-        if self.edge_dim and batch.edge_attr is not None:
-            filt = filt * MLP(
-                features=(F, 3 * F), act="silu", name="edge_filter"
-            )(batch.edge_attr)
-
         scalar_out = MLP(
             features=(F, 3 * F), act="silu", name="scalar_message_mlp"
         )(s)
-        filter_out = filt * scalar_out[snd]
-        gate_v, gate_e, msg_s = jnp.split(filter_out, 3, axis=-1)
+        # The edge work, timed under one scope: the radial filter, both
+        # sender gathers, the products and both receiver sums.
+        with tr.scope("vector_message"):
+            rbf = sinc_basis(dist, self.cutoff, self.num_radial)
+            filt = nn.Dense(3 * F, name="filter_layer")(rbf)
+            filt = filt * cosine_cutoff(dist, self.cutoff)[:, None]
+            if self.edge_dim and batch.edge_attr is not None:
+                filt = filt * MLP(
+                    features=(F, 3 * F), act="silu", name="edge_filter"
+                )(batch.edge_attr)
+            filter_out = filt * scalar_out[snd]
+            gate_v, gate_e, msg_s = jnp.split(filter_out, 3, axis=-1)
 
-        # Vector message: gated neighbor vectors + gated edge directions
-        # (reference divides the already-normalized displacement by the
-        # distance again, PAINNStack.py:255-258 — behavior kept).
-        msg_v = v[snd] * gate_v[:, None, :] + gate_e[:, None, :] * (
-            unit / jnp.maximum(dist, 1e-9)[:, None]
-        )[:, :, None]
+            # Vector message: gated neighbor vectors + gated edge
+            # directions over d once more (module docstring).
+            msg_v = v[snd] * gate_v[:, None, :] + gate_e[:, None, :] * (
+                unit / jnp.maximum(dist, 1e-9)[:, None]
+            )[:, :, None]
 
-        n = batch.num_nodes
-        # Both channels ride the planned-kernel dispatch. The [E, 3, F]
-        # vector message folds its 3-axis into the feature dim — the
-        # reduce is linear, so it commutes with the (row-major) reshape
-        # and the fold is bit-identical to the 3-D masked scatter.
-        s = s + aggregate_receivers(msg_s, batch)
-        e, _, fv = msg_v.shape
-        v = v + aggregate_receivers(msg_v.reshape(e, 3 * fv), batch).reshape(
-            n, 3, fv
-        )
+            n = batch.num_nodes
+            # Both channels ride the planned-kernel dispatch. The [E, 3, F]
+            # vector message folds its 3-axis into the feature dim — the
+            # reduce is linear, so it commutes with the (row-major) reshape
+            # and the fold is bit-identical to the 3-D masked scatter.
+            s = s + aggregate_receivers(msg_s, batch)
+            e, _, fv = msg_v.shape
+            v = v + aggregate_receivers(
+                msg_v.reshape(e, 3 * fv), batch
+            ).reshape(n, 3, fv)
         return s, v
 
 
@@ -203,6 +209,7 @@ class PainnUpdate(nn.Module):
     last_layer: bool = False
 
     @nn.compact
+    @tr.scoped("vector_update")
     def __call__(self, s: jax.Array, v: jax.Array):
         F = self.node_size
         # bias=False is REQUIRED for equivariance: v is [N, 3, F] and a
@@ -251,9 +258,15 @@ class _PainnLayout(nn.Module):
         # With GPS global attention the input embedding lifts node (and
         # edge) features to hidden_dim before the stack (reference
         # PAINNStack._embedding, hydragnn/models/PAINNStack.py:173-186;
-        # wrapped per conv by Base._apply_global_attn:234-247), so every
-        # layer runs at hidden width.
-        if cfg.use_global_attn:
+        # wrapped per conv by Base._apply_global_attn:234-247), and with
+        # species_embedding the atom type is embedded into hidden_dim
+        # (PaiNN's Embedding(Z, F)), so every layer runs at hidden width.
+        self.species_embed = (
+            nn.Dense(cfg.hidden_dim, use_bias=False, name="species_embed")
+            if cfg.species_embedding
+            else None
+        )
+        if cfg.use_global_attn or cfg.species_embedding:
             in_dims = [cfg.hidden_dim] * cfg.num_conv_layers
         else:
             in_dims = [cfg.input_dim] + [cfg.hidden_dim] * (
@@ -301,10 +314,11 @@ class _PainnLayout(nn.Module):
             batch.edge_shifts,
             normalize=True,
         )
-        v = jnp.zeros(
-            (batch.num_nodes, 3, batch.x.shape[-1]), batch.x.dtype
-        )
-        return batch.x, v, {"unit": unit, "dist": dist}
+        s = batch.x
+        if self.species_embed is not None:
+            s = self.species_embed(species_onehot(s, batch.node_mask))
+        v = jnp.zeros((batch.num_nodes, 3, s.shape[-1]), s.dtype)
+        return s, v, {"unit": unit, "dist": dist}
 
     def conv(self, i, inv, equiv, batch, extras):
         cfg = self.cfg

@@ -36,6 +36,18 @@ def activation(name: str) -> Callable[[jax.Array], jax.Array]:
     return table[name]
 
 
+NUM_ELEMENTS = 118  # the periodic table
+
+
+def species_onehot(x: jax.Array, node_mask: jax.Array) -> jax.Array:
+    """One-hot atomic number over the periodic table [N, 118]: the first
+    input column is Z, rounded and clamped into 1..118; padding nodes get
+    zero rows (reference MACEStack.process_node_attributes, :510-541)."""
+    z = jnp.clip(jnp.round(x[:, 0]), 1, NUM_ELEMENTS).astype(jnp.int32)
+    oh = jax.nn.one_hot(z - 1, NUM_ELEMENTS, dtype=x.dtype)
+    return oh * node_mask[:, None].astype(x.dtype)
+
+
 def shifted_softplus(x: jax.Array) -> jax.Array:
     """softplus(x) - log(2): SchNet's activation (zero at zero)."""
     return jax.nn.softplus(x) - jnp.log(2.0).astype(x.dtype)

@@ -32,7 +32,7 @@ import numpy as np
 from flax import linen as nn
 
 from hydragnn_tpu.data.graph import GraphBatch
-from hydragnn_tpu.models.layers import MLP
+from hydragnn_tpu.models.layers import MLP, NUM_ELEMENTS, species_onehot
 from hydragnn_tpu.models.spec import ModelConfig
 from hydragnn_tpu.ops import (
     agnesi_transform,
@@ -47,8 +47,6 @@ from hydragnn_tpu.ops import (
 )
 from hydragnn_tpu.ops.e3 import real_wigner_3j, sh_basis, sh_dim
 from hydragnn_tpu.ops.symmetric_contraction import SymmetricContraction
-
-NUM_ELEMENTS = 118  # full periodic table (reference MACEStack.py:124-127)
 
 # Covalent radii in Angstrom, index = atomic number Z (0 unused), Cordero
 # et al. 2008 / Pyykkoe for the heavy elements — the table the reference's
@@ -371,16 +369,6 @@ class MACEStack(nn.Module):
             c, use_bias=False, name="node_embedding"
         )
 
-    def _onehot(self, batch: GraphBatch) -> jax.Array:
-        """One-hot Z over the periodic table (reference
-        process_node_attributes, MACEStack.py:510-541): first input
-        column is the atomic number, clamped into 1..118."""
-        z = jnp.clip(jnp.round(batch.x[:, 0]), 1, NUM_ELEMENTS).astype(
-            jnp.int32
-        )
-        oh = jax.nn.one_hot(z - 1, NUM_ELEMENTS, dtype=batch.x.dtype)
-        return oh * batch.node_mask[:, None].astype(batch.x.dtype)
-
     def embed(
         self, batch: GraphBatch
     ) -> Tuple[jax.Array, Optional[jax.Array], Dict[str, Any]]:
@@ -400,7 +388,7 @@ class MACEStack(nn.Module):
             pos, batch.senders, batch.receivers, batch.edge_shifts
         )
         edge_sh = sh_basis(vec, cfg.max_ell, normalize=True)
-        onehot = self._onehot(batch)
+        onehot = species_onehot(batch.x, batch.node_mask)
 
         # Radial embedding (reference RadialEmbeddingBlock, blocks.py:141):
         # the cutoff sees the RAW length; the basis sees the (optionally)

@@ -35,6 +35,12 @@ class HeadSpec:
     dim: int
 
 
+MATMUL_PRECISIONS = (None, "default", "high", "highest")
+
+#: Stacks that embed the atomic number when ``species_embedding`` is set.
+SPECIES_EMBEDDING_STACKS = frozenset({"PAINN", "PNAEq"})
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     mpnn_type: str = "SchNet"
@@ -68,6 +74,14 @@ class ModelConfig:
 
     # Edge features
     edge_dim: Optional[int] = None
+
+    # Atom-type embedding before the first block: the one-hot atomic
+    # number (first input column, Z in 1..118) through a bias-free
+    # Dense(hidden_dim), as PaiNN's Embedding(Z, F), so that every block
+    # runs at hidden_dim. Honoured by SPECIES_EMBEDDING_STACKS only; the
+    # reference HydraGNN has no such key (its PaiNN runs block 1 at the
+    # input width).
+    species_embedding: bool = False
 
     # Equivariance (EGNN/SchNet coordinate updates; reference
     # config_utils.py update_config_equivariance)
@@ -112,8 +126,32 @@ class ModelConfig:
 
     # Norm/precision
     conv_checkpointing: bool = False
+    # Training.matmul_precision: the precision of every matrix product of
+    # the model, forward and backward ("default", "high", "highest", as
+    # jax.default_matmul_precision takes them). None leaves the platform's
+    # default: on a TPU float32 operands are rounded to bfloat16. The
+    # reference HydraGNN, on PyTorch, multiplies float32 in float32 unless
+    # TF32 is switched on; "highest" is that here.
+    matmul_precision: Optional[str] = None
 
     # ------------------------------------------------------------------
+    def __post_init__(self):
+        if self.matmul_precision not in MATMUL_PRECISIONS:
+            raise ValueError(
+                f"matmul_precision must be one of {MATMUL_PRECISIONS}; "
+                f"got {self.matmul_precision!r}"
+            )
+        if self.species_embedding and (
+            self.mpnn_type not in SPECIES_EMBEDDING_STACKS
+            or self.use_global_attn
+        ):
+            raise ValueError(
+                "species_embedding is honoured by "
+                f"{sorted(SPECIES_EMBEDDING_STACKS)} without global "
+                f"attention; got mpnn_type {self.mpnn_type!r}, "
+                f"global_attn_engine {self.global_attn_engine!r}"
+            )
+
     @property
     def num_heads(self) -> int:
         return len(self.heads)
@@ -225,6 +263,7 @@ def model_config_from_dict(config: dict) -> ModelConfig:
         num_before_skip=_opt_int(arch.get("num_before_skip")),
         num_after_skip=_opt_int(arch.get("num_after_skip")),
         edge_dim=_opt_int(arch.get("edge_dim")),
+        species_embedding=bool(arch.get("species_embedding", False)),
         equivariance=bool(arch.get("equivariance") or False),
         pna_deg=None if pna_deg is None else tuple(int(x) for x in pna_deg),
         avg_num_neighbors=_opt_float(arch.get("avg_num_neighbors")),
@@ -254,6 +293,7 @@ def model_config_from_dict(config: dict) -> ModelConfig:
         force_weight=float(arch.get("force_weight", 0.0)),
         num_nodes=_opt_int(arch.get("num_nodes")),
         conv_checkpointing=bool(training.get("conv_checkpointing", False)),
+        matmul_precision=training.get("matmul_precision"),
     )
 
 

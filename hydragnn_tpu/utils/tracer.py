@@ -244,6 +244,8 @@ SCOPES = (
     "guard",
     "triplet",
     "triplet_basis",
+    "vector_message",
+    "vector_update",
 )
 
 
